@@ -24,6 +24,9 @@ def main() -> None:
                     help="write suite rows + env metadata to this path")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import (bench_ablation, bench_alpha, bench_beta, bench_degrees,
                    bench_fresh, bench_indexing, bench_io_pipeline,
                    bench_kernels, bench_memory, bench_nio_recall,

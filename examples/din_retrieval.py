@@ -24,9 +24,11 @@ from repro.core.engine import BAMGIndex, BAMGParams  # noqa: E402
 from repro.data.synthetic import din_batch  # noqa: E402
 from repro.models.recsys.din import (DINConfig, init_params,  # noqa: E402
                                      loss_fn, user_interest_vector)
+from repro.utils.compile_cache import use_compile_cache  # noqa: E402
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = DINConfig(n_items=20_000, n_cates=128, seq_len=24, embed_dim=16,
                     attn_mlp=(32, 16), mlp=(64, 32))
     params = init_params(cfg, jax.random.PRNGKey(0))

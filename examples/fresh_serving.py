@@ -27,6 +27,7 @@ from repro.core.engine import BAMGParams  # noqa: E402
 from repro.data.synthetic import make_vector_dataset  # noqa: E402
 from repro.index.delta import DeltaParams, FreshService  # noqa: E402
 from repro.serve import EngineConfig  # noqa: E402
+from repro.utils.compile_cache import use_compile_cache  # noqa: E402
 
 K, L = 10, 48
 
@@ -42,6 +43,7 @@ def recall(svc, queries, k=K):
 
 
 def main() -> None:
+    use_compile_cache()
     ds = make_vector_dataset("fresh", n=2000, d=32, nq=16, k_gt=K,
                              n_clusters=16, seed=0)
     svc = FreshService(tempfile.mkdtemp(prefix="fresh-"),

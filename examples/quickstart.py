@@ -11,9 +11,11 @@ import numpy as np  # noqa: E402
 
 from repro.core.engine import BAMGIndex, BAMGParams  # noqa: E402
 from repro.data.synthetic import make_vector_dataset  # noqa: E402
+from repro.utils.compile_cache import use_compile_cache  # noqa: E402
 
 
 def main() -> None:
+    use_compile_cache()
     # 1. a corpus with exact ground truth ------------------------------------
     ds = make_vector_dataset("quickstart", n=2000, d=64, nq=20, k_gt=10,
                              seed=0)

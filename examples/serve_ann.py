@@ -30,9 +30,11 @@ from repro.data.synthetic import make_vector_dataset  # noqa: E402
 from repro.serve import (EngineConfig, Scheduler,  # noqa: E402
                          SchedulerConfig, ShardedFrontend, make_requests,
                          summarize)
+from repro.utils.compile_cache import use_compile_cache  # noqa: E402
 
 
 def main() -> None:
+    use_compile_cache()
     n_shards = 4
     k = 10
     ds = make_vector_dataset("serve", n=4000, d=64, nq=32, k_gt=10, seed=0)

@@ -15,12 +15,16 @@ import numpy as np
 
 @functools.partial(jax.jit, static_argnames=())
 def _sq_l2(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """(na,d),(nb,d) -> (na,nb) squared L2 via the expanded form (MXU-friendly)."""
+    """(na,d),(nb,d) -> (na,nb) squared L2 via the expanded form (MXU-friendly).
+
+    The cross term runs at full f32 precision: exact ground truth must not
+    ride on a single bf16 MXU pass."""
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
     a2 = jnp.sum(a * a, axis=1, keepdims=True)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)
-    d = a2 + b2.T - 2.0 * (a @ b.T)
+    ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    d = a2 + b2.T - 2.0 * ab
     return jnp.maximum(d, 0.0)
 
 

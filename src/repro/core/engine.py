@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import io
+import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -465,15 +466,26 @@ class BAMGIndex:
         self.nav = nav
         self.params = params
         self.cost = store.scheduler.cost
+        # seconds per construction stage, in build order (set by `build`)
+        self.build_seconds: dict[str, float] = {}
 
     @classmethod
     def build(cls, x: np.ndarray, params: BAMGParams = BAMGParams()):
         p = dataclasses.replace(params)        # configure_io mutates in place
         builder = _builder_for(p)
+        seconds, t0 = {}, time.perf_counter()
+
+        def lap(stage: str) -> None:
+            nonlocal t0
+            t1 = time.perf_counter()
+            seconds[stage], t0 = t1 - t0, t1
+
         nsg_adj, entry = builder.build_nsg(x, r=p.r, l_build=p.l_build,
                                            knn_k=p.knn_k, seed=p.seed)
+        lap("nsg")
         capacity = p.capacity or max_capacity_for(p.r)
         blocks = bnf_blocks(nsg_adj, capacity, seed=p.seed)
+        lap("bnf_blocks")
         if p.use_bmrng_prune:
             graph = builder.refine_bamg(x, nsg_adj, entry, blocks, capacity,
                                         alpha=p.alpha, beta=p.beta,
@@ -484,15 +496,22 @@ class BAMGIndex:
                               members=block_members(blocks, capacity),
                               entry=entry, capacity=capacity,
                               alpha=p.alpha, beta=p.beta)
+        lap("refine_bamg")
         m = p.pq_m or _pick_pq_m(x.shape[1])
         codec = train_pq(x, m=m, seed=p.seed)
         codes = codec.encode(x)
+        lap("pq")
         nav = None
         if p.use_nav:
             nav = build_navgraph(x, graph, alpha=p.alpha, beta=p.beta,
-                                 gamma=p.gamma, capacity=capacity, seed=p.seed)
+                                 gamma=p.gamma, capacity=capacity, seed=p.seed,
+                                 build=builder.build_bamg)
+        lap("navgraph")
         store = _make_decoupled_store(x, graph, nav, p)
-        return cls(x, graph, codec, codes, store, nav, p)
+        lap("store")
+        idx = cls(x, graph, codec, codes, store, nav, p)
+        idx.build_seconds = seconds
+        return idx
 
     @classmethod
     def from_graph(cls, x: np.ndarray, graph: BAMGGraph,
@@ -509,7 +528,8 @@ class BAMGIndex:
         if p.use_nav:
             nav = build_navgraph(x, graph, alpha=p.alpha, beta=p.beta,
                                  gamma=p.gamma, capacity=graph.capacity,
-                                 seed=p.seed)
+                                 seed=p.seed,
+                                 build=_builder_for(p).build_bamg)
         store = _make_decoupled_store(x, graph, nav, p)
         return cls(x, graph, codec, codes, store, nav, p)
 

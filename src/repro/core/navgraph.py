@@ -99,8 +99,13 @@ def build_navgraph(
     knn_k: int = 24,
     seed: int = 0,
     max_layers: int = 8,
+    build=build_bamg,
 ) -> NavGraph:
-    """Algorithm 3.  `base` is the already-built disk BAMG over all of x."""
+    """Algorithm 3.  `base` is the already-built disk BAMG over all of x.
+
+    `build` constructs each layer's BAMG (`core.bamg.build_bamg` keyword
+    signature); the index passes its `GraphBuilder.build_bamg`, so a
+    batched index builds its navigation layers batched too."""
     capacity = capacity if capacity is not None else base.capacity
     layers: list[NavLayer] = []
     cur_graph = base
@@ -113,15 +118,15 @@ def build_navgraph(
         sub_x = x[sel_vids]
         if len(sel_vids) <= max(gamma, 8) or len(sel_vids) <= capacity:
             # final (topmost) layer: small enough to search directly
-            g = build_bamg(sub_x, capacity=min(capacity, max(2, len(sel_vids))),
-                           alpha=alpha, beta=beta, r=min(r, len(sel_vids) - 1),
-                           l_build=l_build, knn_k=min(knn_k, len(sel_vids) - 1),
-                           seed=seed)
+            g = build(sub_x, capacity=min(capacity, max(2, len(sel_vids))),
+                      alpha=alpha, beta=beta, r=min(r, len(sel_vids) - 1),
+                      l_build=l_build, knn_k=min(knn_k, len(sel_vids) - 1),
+                      seed=seed)
             layers.append(NavLayer(vids=sel_vids, adj=g.adj, entry=g.entry))
             break
-        g = build_bamg(sub_x, capacity=capacity, alpha=alpha, beta=beta,
-                       r=min(r, len(sel_vids) - 1), l_build=l_build,
-                       knn_k=min(knn_k, len(sel_vids) - 1), seed=seed)
+        g = build(sub_x, capacity=capacity, alpha=alpha, beta=beta,
+                  r=min(r, len(sel_vids) - 1), l_build=l_build,
+                  knn_k=min(knn_k, len(sel_vids) - 1), seed=seed)
         layers.append(NavLayer(vids=sel_vids, adj=g.adj, entry=g.entry))
         cur_graph = g
         cur_vids = sel_vids

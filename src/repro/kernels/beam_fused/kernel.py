@@ -32,7 +32,7 @@ Two execution modes share the hop loop and differ only in where the
 corpus lives:
 
 - **resident** (`beam_hops_{adc,l2}_pallas`): adjacency + codes/vectors
-  are VMEM blocks, gather chunks come from `dynamic_slice`.  Footprint
+  are VMEM blocks, gather chunks are `pl.ds` slices of them.  Footprint
   per grid step is N*(R + M)*4 bytes (adc) or N*(R + D + 1)*4 (l2) plus
   the (TB*R, n_chunk) gather one-hot and (TB, R|L, L) merge tensors --
   see `vmem_bytes`.  A 100k-node shard at R=32, M=16 is ~20 MB, past
@@ -64,6 +64,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _SENT = float(2 ** 31)   # f32 id sentinel: -1 ids rank last, like pool_merge
+_LANES = 128             # f32 vreg width: VMEM/HBM rows pad to it
+# one-hot contractions carry exact f32 ids and table entries; a bf16 MXU
+# pass would round every id above 256, so their precision is pinned
+_EXACT = jax.lax.Precision.HIGHEST
 
 # resident-fused VMEM budget the auto backend compares `vmem_bytes`
 # against; ~16 MiB is a safe per-core figure across TPU generations
@@ -73,6 +77,11 @@ _DEFAULT_VMEM_BUDGET = 16 * 2 ** 20
 def vmem_budget_bytes() -> int:
     """The resident-fused VMEM budget (bytes); REPRO_VMEM_BUDGET overrides."""
     return int(os.environ.get("REPRO_VMEM_BUDGET", _DEFAULT_VMEM_BUDGET))
+
+
+def _lanes(c: int) -> int:
+    """`c` columns rounded up to whole 128-lane vregs."""
+    return -(-c // _LANES) * _LANES
 
 
 def _mode_dims(m, d):
@@ -116,12 +125,14 @@ def stream_vmem_bytes(n: int, r: int, *, m: int | None = None,
                       k: int = 256) -> int:
     """Estimated VMEM footprint of one *streaming* fused grid step: the
     resident estimate minus the corpus blocks, plus the two double-
-    buffered (2, n_chunk, R|row_w) DMA slabs -- O(n_chunk), not O(n)."""
+    buffered (2, n_chunk, R|row_w) DMA slabs, lane-padded to 128 --
+    O(n_chunk), not O(n)."""
     row_w, _ = _mode_dims(m, d)
     resident = vmem_bytes(n, r, m=m, d=d, l=l, max_hops=max_hops,
                           tile_b=tile_b, n_chunk=n_chunk, k=k)
     f = 4
-    return resident - n * (r + row_w) * f + 2 * n_chunk * (r + row_w) * f
+    slabs = 2 * n_chunk * (_lanes(r) + _lanes(row_w)) * f
+    return resident - n * (r + row_w) * f + slabs
 
 
 def fits_vmem(n: int, r: int, *, m: int | None = None, d: int | None = None,
@@ -151,38 +162,58 @@ def _check_tiling(b: int, tile_b: int, n: int, n_chunk: int) -> None:
             f"this)")
 
 
-def _gather_rows(ids_col, mat, n: int, n_chunk: int):
-    """One-hot gather of `mat` rows: ids_col (S, 1) exact-int f32 with all
-    values in [0, n); mat (N, C) f32.  Returns (S, C).  Chunked over N so
-    only an (S, n_chunk) one-hot tile is live per iteration; each id
+def _iota_f32(shape, dim: int):
+    """f32 index iota (Mosaic's iota is integer-only; ids < 2^24 are exact)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.float32)
+
+
+def _onehot_dot(onehot, mat):
+    """(S, C) one-hot @ (C, W) at full f32 precision: an exact row gather."""
+    return jax.lax.dot_general(onehot, mat, (((1,), (0,)), ((), ())),
+                               precision=_EXACT,
+                               preferred_element_type=jnp.float32)
+
+
+def _column(a):
+    """(T, R) -> (T*R, 1) in row-major order.  Mosaic refuses the direct
+    lane->sublane reshape, so transpose and stack the columns instead."""
+    at = a.T
+    return jnp.concatenate([at[:, i:i + 1] for i in range(a.shape[0])],
+                           axis=0)
+
+
+def _gather_rows(ids_col, mat_ref, n: int, n_chunk: int):
+    """One-hot gather of `mat_ref` rows: ids_col (S, 1) exact-int f32 with
+    all values in [0, n); mat_ref (N, C) f32.  Returns (S, C).  Chunked over
+    N so only an (S, n_chunk) one-hot tile is live per iteration; each id
     matches exactly one column of exactly one chunk."""
     s = ids_col.shape[0]
-    c = mat.shape[1]
-    col = jax.lax.broadcasted_iota(jnp.float32, (s, n_chunk), 1)
+    c = mat_ref.shape[1]
+    col = _iota_f32((s, n_chunk), 1)
 
     def body(ci, acc):
         off = (ci * n_chunk).astype(jnp.float32)
         onehot = (col + off == ids_col).astype(jnp.float32)
-        chunk = jax.lax.dynamic_slice_in_dim(mat, ci * n_chunk, n_chunk, 0)
-        return acc + jax.lax.dot_general(
-            onehot, chunk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        start = pl.multiple_of(ci * n_chunk, n_chunk)
+        return acc + _onehot_dot(onehot, mat_ref[pl.ds(start, n_chunk), :])
 
     return jax.lax.fori_loop(0, n // n_chunk, body,
                              jnp.zeros((s, c), jnp.float32))
 
 
-def _gather_rows_stream(ids_col, hbm_ref, buf, sem, n: int, n_chunk: int):
+def _gather_rows_stream(ids_col, hbm_ref, buf, sem, n: int, n_chunk: int,
+                        width: int):
     """`_gather_rows` with the corpus in HBM: the slab for chunk i is
     DMA'd into one slot of the (2, n_chunk, C) VMEM scratch `buf` while
     the one-hot tile contracts the other slot (double buffering --
     `make_async_copy` for slab i+1 is started before the wait on slab i).
-    Same chunk order and contents as the resident gather, so the f32
-    accumulation -- and therefore every downstream output -- is
+    HBM rows are lane-padded (`_lane_pad`); the first `width` columns are
+    returned.  Same chunk order and contents as the resident gather, so
+    the f32 accumulation -- and therefore every downstream output -- is
     bit-identical."""
     s = ids_col.shape[0]
     c = hbm_ref.shape[1]
-    col = jax.lax.broadcasted_iota(jnp.float32, (s, n_chunk), 1)
+    col = _iota_f32((s, n_chunk), 1)
     num = n // n_chunk
 
     def dma(slot, ci):
@@ -202,11 +233,10 @@ def _gather_rows_stream(ids_col, hbm_ref, buf, sem, n: int, n_chunk: int):
         dma(slot, ci).wait()
         off = (ci * n_chunk).astype(jnp.float32)
         onehot = (col + off == ids_col).astype(jnp.float32)
-        return acc + jax.lax.dot_general(
-            onehot, buf[slot], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        return acc + _onehot_dot(onehot, buf[slot])
 
-    return jax.lax.fori_loop(0, num, body, jnp.zeros((s, c), jnp.float32))
+    acc = jax.lax.fori_loop(0, num, body, jnp.zeros((s, c), jnp.float32))
+    return acc[:, :width]
 
 
 def _merge_ranked(pids, pd, pexp, cids, cd, tb: int, l: int, r: int):
@@ -256,7 +286,7 @@ def _merge_ranked(pids, pd, pexp, cids, cd, tb: int, l: int, r: int):
 def _hop_loop(gather_adj, ids_ref, d_ref, exp_ref, score, outs,
               *, max_hops: int, r: int):
     """Shared hop loop; `gather_adj(v_col (TB, 1)) -> (TB, R)` pulls the
-    frontier adjacency rows (resident dynamic_slice chunks or streamed
+    frontier adjacency rows (resident `pl.ds` chunks or streamed
     HBM slabs) and `score(nbrs, valid) -> (TB, R)` closes over the
     mode-specific operands.  Writes the eight output refs in `outs`."""
     (oi_ref, od_ref, oe_ref, oh_ref, oti_ref, otd_ref,
@@ -313,19 +343,12 @@ def _adc_score_from(gather_codes, tables, tb: int, r: int):
     kio = jax.lax.broadcasted_iota(jnp.int32, (tb, r, k_cent), 2)
 
     def score(nbrs, valid):
-        nbc = jnp.maximum(nbrs, 0.0).reshape(tb * r, 1)
-        ncodes = gather_codes(nbc)                               # (TB*R, M)
+        ncodes = gather_codes(_column(jnp.maximum(nbrs, 0.0)))   # (TB*R, M)
         ncodes = ncodes.astype(jnp.int32).reshape(tb, r, m_sub)
-
-        def body(mi, acc):
-            c_m = jax.lax.dynamic_slice_in_dim(ncodes, mi, 1, axis=2)
-            onehot = (kio == c_m).astype(jnp.float32)            # (TB, R, K)
-            t_m = jax.lax.dynamic_slice_in_dim(tables, mi, 1, axis=1)
-            t_m = t_m.reshape(tb, 1, k_cent)
-            return acc + jnp.sum(onehot * t_m, axis=2)           # (TB, R)
-
-        nd = jax.lax.fori_loop(0, m_sub, body,
-                               jnp.zeros((tb, r), jnp.float32))
+        nd = jnp.zeros((tb, r), jnp.float32)
+        for mi in range(m_sub):
+            onehot = (kio == ncodes[:, :, mi:mi + 1]).astype(jnp.float32)
+            nd = nd + jnp.sum(onehot * tables[:, mi:mi + 1, :], axis=2)
         return jnp.where(valid, nd, jnp.inf)
 
     return score
@@ -337,11 +360,12 @@ def _l2_score_from(gather_xn, q, dd: int, tb: int, r: int):
     qn = jnp.sum(q * q, axis=1)
 
     def score(nbrs, valid):
-        nbc = jnp.maximum(nbrs, 0.0).reshape(tb * r, 1)
-        rows = gather_xn(nbc)                                    # (TB*R, D+1)
-        vecs = rows[:, :dd].reshape(tb, r, dd)
-        n2g = rows[:, dd].reshape(tb, r)
+        rows = gather_xn(_column(jnp.maximum(nbrs, 0.0)))        # (TB*R, D+1)
+        rows = rows.reshape(tb, r, dd + 1)
+        vecs = rows[:, :, :dd]
+        n2g = rows[:, :, dd]
         dot = jax.lax.dot_general(vecs, q, (((2,), (1,)), ((0,), (0,))),
+                                  precision=_EXACT,
                                   preferred_element_type=jnp.float32)
         dist = jnp.maximum(n2g - 2.0 * dot + qn[:, None], 0.0)
         return jnp.where(valid, dist, jnp.inf)
@@ -353,12 +377,10 @@ def _beam_adc_kernel(adj_ref, codes_ref, tables_ref, ids_ref, d_ref, exp_ref,
                      *outs, max_hops: int, n: int, n_chunk: int):
     tb = ids_ref.shape[0]
     r = adj_ref.shape[1]
-    adj_f = adj_ref[...]
-    codes_f = codes_ref[...]
     score = _adc_score_from(
-        lambda ids: _gather_rows(ids, codes_f, n, n_chunk),
+        lambda ids: _gather_rows(ids, codes_ref, n, n_chunk),
         tables_ref[...], tb, r)
-    _hop_loop(lambda v: _gather_rows(v, adj_f, n, n_chunk),
+    _hop_loop(lambda v: _gather_rows(v, adj_ref, n, n_chunk),
               ids_ref, d_ref, exp_ref, score, outs,
               max_hops=max_hops, r=r)
 
@@ -368,48 +390,45 @@ def _beam_l2_kernel(adj_ref, xn_ref, q_ref, ids_ref, d_ref, exp_ref,
     tb = ids_ref.shape[0]
     r = adj_ref.shape[1]
     dd = xn_ref.shape[1] - 1                     # last column = squared norm
-    adj_f = adj_ref[...]
-    xn = xn_ref[...]
-    score = _l2_score_from(lambda ids: _gather_rows(ids, xn, n, n_chunk),
+    score = _l2_score_from(lambda ids: _gather_rows(ids, xn_ref, n, n_chunk),
                            q_ref[...], dd, tb, r)
-    _hop_loop(lambda v: _gather_rows(v, adj_f, n, n_chunk),
+    _hop_loop(lambda v: _gather_rows(v, adj_ref, n, n_chunk),
               ids_ref, d_ref, exp_ref, score, outs,
               max_hops=max_hops, r=r)
 
 
 def _beam_adc_stream_kernel(adj_ref, codes_ref, tables_ref, ids_ref, d_ref,
                             exp_ref, *outs_scratch,
-                            max_hops: int, n: int, n_chunk: int):
+                            max_hops: int, n: int, n_chunk: int, r: int):
     """ADC hop loop with adj/codes left in HBM (`memory_space=ANY`) and
     every gather streamed through the double-buffered DMA scratch."""
     *outs, adj_buf, adj_sem, code_buf, code_sem = outs_scratch
     tb = ids_ref.shape[0]
-    r = adj_ref.shape[1]
+    m_sub = tables_ref.shape[1]
     score = _adc_score_from(
         lambda ids: _gather_rows_stream(ids, codes_ref, code_buf, code_sem,
-                                        n, n_chunk),
+                                        n, n_chunk, m_sub),
         tables_ref[...], tb, r)
     _hop_loop(lambda v: _gather_rows_stream(v, adj_ref, adj_buf, adj_sem,
-                                            n, n_chunk),
+                                            n, n_chunk, r),
               ids_ref, d_ref, exp_ref, score, tuple(outs),
               max_hops=max_hops, r=r)
 
 
 def _beam_l2_stream_kernel(adj_ref, xn_ref, q_ref, ids_ref, d_ref, exp_ref,
                            *outs_scratch,
-                           max_hops: int, n: int, n_chunk: int):
+                           max_hops: int, n: int, n_chunk: int, r: int):
     """Exact-L2 hop loop with adj/vectors left in HBM and every gather
     streamed through the double-buffered DMA scratch."""
     *outs, adj_buf, adj_sem, xn_buf, xn_sem = outs_scratch
     tb = ids_ref.shape[0]
-    r = adj_ref.shape[1]
-    dd = xn_ref.shape[1] - 1
+    dd = q_ref.shape[1]
     score = _l2_score_from(
         lambda ids: _gather_rows_stream(ids, xn_ref, xn_buf, xn_sem,
-                                        n, n_chunk),
+                                        n, n_chunk, dd + 1),
         q_ref[...], dd, tb, r)
     _hop_loop(lambda v: _gather_rows_stream(v, adj_ref, adj_buf, adj_sem,
-                                            n, n_chunk),
+                                            n, n_chunk, r),
               ids_ref, d_ref, exp_ref, score, tuple(outs),
               max_hops=max_hops, r=r)
 
@@ -497,12 +516,20 @@ def beam_hops_l2_pallas(adj, xn, queries, pool_ids, pool_d, pool_exp,
     )(adj, xn, queries, pool_ids, pool_d, pool_exp)
 
 
+def _lane_pad(a):
+    """Pad the columns of an (N, C) HBM operand to a multiple of 128 lanes:
+    Mosaic DMAs only lane-aligned slabs out of HBM."""
+    pad = _lanes(a.shape[1]) - a.shape[1]
+    return a if pad == 0 else jnp.pad(a, ((0, 0), (0, pad)))
+
+
 def _stream_scratch(n_chunk: int, r: int, row_w: int):
     """Double-buffered DMA scratch: (2, n_chunk, C) slab pairs + their
-    completion semaphores, for the adjacency and the codes/vector gathers."""
-    return [pltpu.VMEM((2, n_chunk, r), jnp.float32),
+    completion semaphores, for the lane-padded adjacency and codes/vector
+    gathers."""
+    return [pltpu.VMEM((2, n_chunk, _lanes(r)), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((2, n_chunk, row_w), jnp.float32),
+            pltpu.VMEM((2, n_chunk, _lanes(row_w)), jnp.float32),
             pltpu.SemaphoreType.DMA((2,))]
 
 
@@ -521,10 +548,10 @@ def beam_hops_adc_stream(adj, codes, tables, pool_ids, pool_d, pool_exp,
     b, l = pool_ids.shape
     n = adj.shape[0]
     _check_tiling(b, tile_b, n, n_chunk)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_beam_adc_stream_kernel, max_hops=max_hops, n=n,
-                          n_chunk=n_chunk),
+                          n_chunk=n_chunk, r=adj.shape[1]),
         grid=(b // tile_b,),
         in_specs=[
             any_spec,
@@ -538,7 +565,7 @@ def beam_hops_adc_stream(adj, codes, tables, pool_ids, pool_d, pool_exp,
         out_shape=_out_shapes(b, l, max_hops),
         scratch_shapes=_stream_scratch(n_chunk, adj.shape[1], codes.shape[1]),
         interpret=interpret,
-    )(adj, codes, tables, pool_ids, pool_d, pool_exp)
+    )(_lane_pad(adj), _lane_pad(codes), tables, pool_ids, pool_d, pool_exp)
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "tile_b", "n_chunk",
@@ -552,10 +579,10 @@ def beam_hops_l2_stream(adj, xn, queries, pool_ids, pool_d, pool_exp,
     b, l = pool_ids.shape
     n = adj.shape[0]
     _check_tiling(b, tile_b, n, n_chunk)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_beam_l2_stream_kernel, max_hops=max_hops, n=n,
-                          n_chunk=n_chunk),
+                          n_chunk=n_chunk, r=adj.shape[1]),
         grid=(b // tile_b,),
         in_specs=[
             any_spec,
@@ -569,4 +596,4 @@ def beam_hops_l2_stream(adj, xn, queries, pool_ids, pool_d, pool_exp,
         out_shape=_out_shapes(b, l, max_hops),
         scratch_shapes=_stream_scratch(n_chunk, adj.shape[1], xn.shape[1]),
         interpret=interpret,
-    )(adj, xn, queries, pool_ids, pool_d, pool_exp)
+    )(_lane_pad(adj), _lane_pad(xn), queries, pool_ids, pool_d, pool_exp)

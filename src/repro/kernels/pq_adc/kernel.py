@@ -19,24 +19,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+# one-hot x table contractions must stay exact (a bf16 MXU pass would
+# round the table entries), so their precision is pinned
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _adc_kernel(codes_ref, tables_ref, out_ref, *, m_sub: int, k_cent: int):
-    """codes (TN, M) int32 | tables (TB, M, K) f32 -> out (TN, TB) f32."""
+    """codes (TN, M) int32 | tables (TB, M, K) f32 -> out (TB, TN) f32."""
     tn = codes_ref.shape[0]
     tb = tables_ref.shape[0]
     codes = codes_ref[...]                      # (TN, M)
+    tables = tables_ref[...]                    # (TB, M, K)
     col = jax.lax.broadcasted_iota(jnp.int32, (tn, k_cent), 1)
-
-    def body(m, acc):
-        c_m = jax.lax.dynamic_slice_in_dim(codes, m, 1, axis=1)   # (TN, 1)
-        onehot = (col == c_m).astype(jnp.float32)                 # (TN, K)
-        t_m = jax.lax.dynamic_slice_in_dim(tables_ref[...], m, 1, axis=1)
-        t_m = t_m.reshape(tb, k_cent)                             # (TB, K)
-        return acc + jax.lax.dot_general(
-            onehot, t_m, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                   # (TN, TB)
-
-    acc = jnp.zeros((tn, tb), jnp.float32)
-    out_ref[...] = jax.lax.fori_loop(0, m_sub, body, acc)
+    acc = jnp.zeros((tb, tn), jnp.float32)
+    for m in range(m_sub):                      # static: Mosaic slices
+        onehot = (col == codes[:, m:m + 1]).astype(jnp.float32)  # (TN, K)
+        acc = acc + jax.lax.dot_general(
+            tables[:, m, :], onehot, (((1,), (1,)), ((), ())),
+            precision=_EXACT, preferred_element_type=jnp.float32)  # (TB, TN)
+    out_ref[...] = acc
 
 
 def _adc_rowwise_kernel(codes_ref, tables_ref, out_ref, *, m_sub: int,
@@ -44,17 +45,13 @@ def _adc_rowwise_kernel(codes_ref, tables_ref, out_ref, *, m_sub: int,
     """codes (TB, R, M) int32 | tables (TB, M, K) f32 -> out (TB, R) f32."""
     tb, r, _ = codes_ref.shape
     codes = codes_ref[...]                          # (TB, R, M)
+    tables = tables_ref[...]                        # (TB, M, K)
     col = jax.lax.broadcasted_iota(jnp.int32, (tb, r, k_cent), 2)
-
-    def body(m, acc):
-        c_m = jax.lax.dynamic_slice_in_dim(codes, m, 1, axis=2)   # (TB, R, 1)
-        onehot = (col == c_m).astype(jnp.float32)                 # (TB, R, K)
-        t_m = jax.lax.dynamic_slice_in_dim(tables_ref[...], m, 1, axis=1)
-        t_m = t_m.reshape(tb, 1, k_cent)                          # (TB, 1, K)
-        return acc + jnp.sum(onehot * t_m, axis=2)                # (TB, R)
-
-    out_ref[...] = jax.lax.fori_loop(
-        0, m_sub, body, jnp.zeros((tb, r), jnp.float32))
+    acc = jnp.zeros((tb, r), jnp.float32)
+    for m in range(m_sub):
+        onehot = (col == codes[:, :, m:m + 1]).astype(jnp.float32)  # (TB,R,K)
+        acc = acc + jnp.sum(onehot * tables[:, m:m + 1, :], axis=2)
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
@@ -100,15 +97,14 @@ def pq_adc_pallas(tables: jnp.ndarray, codes: jnp.ndarray,
     assert n % tile_n == 0 and b % tile_b == 0, (n, b, tile_n, tile_b)
     codes = codes.astype(jnp.int32)
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_adc_kernel, m_sub=m_sub, k_cent=k_cent),
         grid=(n // tile_n, b // tile_b),
         in_specs=[
             pl.BlockSpec((tile_n, m_sub), lambda i, j: (i, 0)),
             pl.BlockSpec((tile_b, m_sub, k_cent), lambda i, j: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_n, tile_b), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
+        out_specs=pl.BlockSpec((tile_b, tile_n), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         interpret=interpret,
     )(codes, tables)
-    return out.T  # (B, N)

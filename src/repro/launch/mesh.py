@@ -11,14 +11,14 @@ jax device state.
 from __future__ import annotations
 
 import jax
-
-from repro.utils.sharding import make_mesh_compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1, data: int | None = None):
@@ -47,4 +47,5 @@ def make_host_mesh(model: int = 1, data: int | None = None):
         raise ValueError(
             f"make_host_mesh: a ({data}, {model}) mesh needs "
             f"{data * model} devices but only {n} exist")
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -26,6 +26,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from ..utils.compile_cache import use_compile_cache
+    use_compile_cache()
     from ..core.engine import (BAMGIndex, BAMGParams, DiskANNIndex,
                                DiskANNParams, StarlingIndex, StarlingParams)
     from ..data.synthetic import make_vector_dataset

@@ -21,8 +21,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.utils.sharding import bound_axis_size as _axis_size
-
 
 def _ring_perm(p: int):
     return [(j, (j + 1) % p) for j in range(p)]
@@ -37,7 +35,7 @@ def ring_gather(table_local: jnp.ndarray, idx: jnp.ndarray,
 
 
 def _ring_gather_fwd_impl(table_local, idx, axis_name):
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     r, d = table_local.shape
     t = idx.shape[0]
@@ -67,7 +65,7 @@ def _fwd(table_local, idx, axis_name):
 def _bwd(axis_name, res, dout):
     idx, proxy = res
     r, dtype = proxy.shape[0], proxy.dtype
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = _ring_perm(p)
     d = dout.shape[1]
@@ -110,7 +108,7 @@ def ring_scatter_add(values: jnp.ndarray, idx: jnp.ndarray,
 
 
 def _ring_scatter_impl(values, idx, axis_name, rows_local):
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = _ring_perm(p)
     d = values.shape[1]

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .layers import act_fn
-from repro.utils.sharding import bound_axis_size
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,7 +106,7 @@ def _moe_group_local(xt, gates, eids, wg, wi, wo, *, cfg: MoEConfig,
     """
     tg, d = xt.shape
     k = cfg.top_k
-    mp = bound_axis_size(model_axis)
+    mp = jax.lax.axis_size(model_axis)
     e_pad = cfg.n_experts_padded
     e_l = e_pad // mp
     n_slot = tg * k
@@ -191,7 +190,7 @@ def _moe_local(xt, gates, eids, wg, wi, wo, *, cfg: MoEConfig,
 
     The group count adapts downward to the largest divisor of the local
     token count."""
-    mp = bound_axis_size(model_axis)
+    mp = jax.lax.axis_size(model_axis)
     t_full, d = xt.shape
     sliced = t_full % mp == 0 and t_full >= mp and (t_full // mp) >= 1
     if sliced:

@@ -33,7 +33,6 @@ from .attention import (causal_attention, decode_attention_local,
 from .layers import (apply_norm, apply_rope, constrain, dense_init,
                      embed_init, gated_mlp, norm_param, softmax_xent_chunked)
 from .moe import MoEConfig, moe_ffn
-from repro.utils.sharding import bound_axis_size
 
 
 # ---------------------------------------------------------------------------
@@ -601,7 +600,7 @@ def _decode_seqsharded(q1, k_new, v_new, ck, cv, write_pos, new_len, ctx,
         s_l = ck_l.shape[1]
         idx = jnp.int32(0)
         for ax in seq_axes:
-            idx = idx * bound_axis_size(ax) + jax.lax.axis_index(ax)
+            idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         start = idx * s_l
         loc = jnp.clip(wp - start, 0, s_l - 1)
         mine = (wp >= start) & (wp < start + s_l)

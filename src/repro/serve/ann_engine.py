@@ -60,6 +60,7 @@ from repro.kernels import beam_fused
 from repro.kernels.beam_fused.ops import beam_hops
 from repro.kernels.l2_topk.ops import l2_topk_rowwise
 from repro.kernels.pq_adc.ops import pq_adc, pq_adc_rowwise
+from repro.utils.faults import InjectedFault
 
 # backend -> the beam_hops backend the fused hop loop dispatches on
 _FUSED_INNER = {"fused": "auto", "fused_pallas": "pallas",
@@ -268,10 +269,11 @@ class BatchedANNEngine:
         return self._fault is None
 
     def inject_fault(self, exc: Optional[Exception] = None) -> None:
-        """Fault hook: every subsequent `search_batch` raises (dead shard)
-        until `heal()` -- lets the sharded front-end's degraded-mode path be
-        exercised without a real device failure."""
-        self._fault = exc if exc is not None else RuntimeError(
+        """Fault hook: every subsequent `search_batch` raises `exc` (an
+        `InjectedFault` by default: a dead shard) until `heal()` -- lets
+        the sharded front-end's degraded-mode path be exercised without a
+        real device failure."""
+        self._fault = exc if exc is not None else InjectedFault(
             "injected engine fault")
 
     def heal(self) -> None:

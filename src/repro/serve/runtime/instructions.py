@@ -18,7 +18,10 @@ down (or whose replica group is exhausted) marks its own and its GATHER's
 slot inactive, so degraded mode is a mask over a static program, never a
 different program and never control-flow-by-exception.  A replica that
 raises during RUN is marked down and the RUN retries on the shard's next
-healthy replica (round-robin) before the shard masks out.
+healthy replica (round-robin) before the shard masks out.  Only a
+replica failure is survived that way -- an `InjectedFault` or a device
+runtime error; an error from tracing, lowering or compiling the engine's
+program is a bug in the program, and propagates.
 
 Merge semantics are bit-identical to the pre-runtime `ShardedFrontend`
 loop: per-shard candidates concatenate in ascending shard order, are
@@ -31,9 +34,16 @@ import dataclasses
 import enum
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
+from repro.utils.faults import InjectedFault
+
 from .placement import ShardPlacement
+
+# what a replica may raise and still only be marked down: a simulated
+# failure, or the device failing at run time
+_REPLICA_FAILURES = (InjectedFault, jax.errors.JaxRuntimeError)
 
 
 class Opcode(enum.IntEnum):
@@ -166,7 +176,7 @@ class InstructionInterpreter:
                 ids_s, d_s = rep.worker.run(rep, st.queries, ks,
                                             l=st.l, max_hops=st.max_hops,
                                             exclude=excl)
-            except Exception as e:  # noqa: BLE001 -- replica down, try next
+            except _REPLICA_FAILURES as e:       # replica down, try next
                 self.placement.record_failure(rep, e)
                 continue
             st.results[s] = (ids_s, d_s, ks)

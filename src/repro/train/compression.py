@@ -20,8 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.utils.sharding import bound_axis_size
-
 
 def compress_bf16(tree):
     return jax.tree.map(lambda g: g.astype(jnp.bfloat16), tree)
@@ -71,7 +69,7 @@ def compressed_psum(tree, axis_names, method: str = "int8_ef",
     """
     n = 1
     for ax in axis_names:
-        n *= bound_axis_size(ax)
+        n *= jax.lax.axis_size(ax)
 
     if method == "none":
         return jax.tree.map(

@@ -8,40 +8,10 @@ Sharding conventions (see DESIGN.md §4):
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence
+from typing import Any, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax <= 0.4.x: meshes are implicitly Auto
-    _AxisType = None
-
-
-def make_mesh_compat(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """`jax.make_mesh` with `axis_types=(AxisType.Auto, ...)` where supported.
-
-    jax 0.4.x has neither `jax.sharding.AxisType` nor the `axis_types`
-    kwarg; its meshes behave as Auto, so omitting the argument is the
-    semantically identical spelling there.
-    """
-    if _AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(_AxisType.Auto,) * len(axes))
-
-
-def bound_axis_size(axis_name) -> int:
-    """Size of a bound mesh axis inside shard_map/pmap, as a Python int.
-
-    `jax.lax.axis_size` only exists on jax >= 0.5; on 0.4.x, `psum` of a
-    Python-literal constant folds to the axis size eagerly.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
 
 def batch_axes(mesh: Mesh) -> tuple:
     """The mesh axes that jointly shard the batch dimension."""

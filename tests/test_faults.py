@@ -258,6 +258,8 @@ def test_frontend_dead_shard_skip_and_recover(frontend, small_corpus):
     assert st.degraded.all() and st.shards_down == (1,)
     assert frontend.health()["shards_down"] == [1]
     assert frontend.health()["per_shard"][1]["errors"] == 1
+    assert frontend.health()["per_shard"][1]["last_error"].startswith(
+        "InjectedFault(")
     deg_rec = recall_at_k(ids, ds.gt, K)
     assert 0 < deg_rec < clean_rec             # partial but useful
     # the marked-down shard is skipped without another engine call

@@ -23,8 +23,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 from repro.models.gnn.ring_gather import ring_gather, ring_scatter_add
-from repro.utils.sharding import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 E, d, T = 64, 16, 200
 table = jnp.asarray(rng.normal(size=(E, d)), jnp.float32)

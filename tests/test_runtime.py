@@ -152,10 +152,58 @@ def test_replica_failover_keeps_shard_up(fleet):
         h = rt.health()
         assert h["shards_up"] == rt.n_shards
         assert h["per_shard"][0]["errors"] >= 1
+        assert h["per_shard"][0]["last_error"].startswith("InjectedFault(")
         assert h["replicas"][0] == [False, True]
     finally:
         rt.engines[0].heal()
         rt.mark_up(0)
+
+
+def _raise_not_implemented(*args, **kwargs):
+    raise NotImplementedError("no lowering for this primitive")
+
+
+@pytest.mark.parametrize("failure", ["raise", "lowering"])
+def test_program_error_propagates_from_serve_batch(fleet, failure):
+    """An engine whose program cannot be traced, lowered or compiled is a
+    bug, not a dead replica: `serve_batch` raises it instead of masking
+    the shard and answering -1/+inf, and the shard stays up."""
+    ds, fe = fleet
+    rt = ServeRuntime(fe.shard_vids, fe.engines, host_indexes=fe.host_indexes)
+    eng = rt.engines[0]
+    if failure == "raise":
+        eng.search_batch = _raise_not_implemented
+        expect = NotImplementedError
+    else:   # a TPU-only Pallas program lowered for the CPU
+        eng.config = dataclasses.replace(eng.config, backend="fused_pallas")
+        expect = ValueError
+    try:
+        with pytest.raises(expect):
+            rt.serve_batch(ds.queries, K)
+        h = rt.health()
+        assert h["shards_up"] == rt.n_shards and h["per_shard"][0]["errors"] == 0
+    finally:
+        eng.__dict__.pop("search_batch", None)
+        eng.config = _CFG
+
+
+def test_injected_fault_still_degrades(fleet):
+    """`inject_fault()` raises `InjectedFault`, which the RUN survives: the
+    shard masks out and the batch is answered, flagged degraded."""
+    from repro.utils.faults import InjectedFault
+    ds, fe = fleet
+    rt = ServeRuntime(fe.shard_vids, fe.engines, host_indexes=fe.host_indexes)
+    rt.engines[2].inject_fault()
+    try:
+        with pytest.raises(InjectedFault):
+            rt.engines[2].search_batch(ds.queries, K)
+        ids, _, st = rt.serve_batch(ds.queries, K, with_status=True)
+        assert st.degraded.all() and st.shards_down == (2,)
+        assert (ids >= 0).all()
+        assert rt.health()["per_shard"][2]["last_error"].startswith(
+            "InjectedFault(")
+    finally:
+        rt.engines[2].heal()
 
 
 def test_runtime_all_shards_down(fleet):

@@ -25,11 +25,11 @@ def _run(snippet: str, timeout=900):
 PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.utils.sharding import make_mesh_compat
+from jax.sharding import AxisType
 from repro.models.transformer import (LMConfig, ShardCtx, init_lm_params,
     lm_loss, serve_prefill, decode_step, init_cache, lm_param_specs,
     cache_specs)
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 ctx, ctx0 = ShardCtx(mesh=mesh), ShardCtx(mesh=None)
 def put(tree, specs):
     return jax.tree.map(lambda x, s: jax.device_put(
@@ -121,7 +121,7 @@ def test_manual_dp_compressed_convergence():
 from repro.train.optimizer import AdamWConfig
 from repro.train.trainer import make_manual_dp_step, make_train_step, init_train_state
 from repro.data.synthetic import lm_batch
-mesh1 = make_mesh_compat((8,), ("data",))
+mesh1 = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = LMConfig(name="c", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
                d_head=8, d_ff=64, vocab=64, remat="none", loss_chunks=2,
                dtype="float32")
