@@ -62,6 +62,8 @@ from repro.kernels.l2_topk.ops import l2_topk_rowwise
 from repro.kernels.pq_adc.ops import pq_adc, pq_adc_rowwise
 from repro.utils.faults import InjectedFault
 
+from . import telemetry
+
 # backend -> the beam_hops backend the fused hop loop dispatches on
 _FUSED_INNER = {"fused": "auto", "fused_pallas": "pallas",
                 "fused_interpret": "interpret", "fused_ref": "ref",
@@ -148,55 +150,59 @@ def batched_search(x, adj, codes, codebooks, entry_cands, entry_codes,
     fused = backend in _FUSED_INNER
     inner = _FUSED_INNER.get(backend, backend)
     stage = _STAGE_INNER.get(inner, inner)
-    tables = _adc_tables(queries, codebooks)               # (B, M, K)
 
     # --- query-sensitive entry selection: pq_adc over the candidate pool
-    ed = pq_adc(tables, entry_codes, backend=stage)        # (B, E)
-    seed_neg, seed_idx = jax.lax.top_k(-ed, n_entry)
-    seed_ids = entry_cands[seed_idx].astype(jnp.int32)     # (B, n_entry)
+    with jax.named_scope("bamg.entry"):
+        tables = _adc_tables(queries, codebooks)           # (B, M, K)
+        ed = pq_adc(tables, entry_codes, backend=stage)    # (B, E)
+        seed_neg, seed_idx = jax.lax.top_k(-ed, n_entry)
+        seed_ids = entry_cands[seed_idx].astype(jnp.int32)  # (B, n_entry)
 
-    pool_ids = jnp.full((b, l), -1, jnp.int32)
-    pool_d = jnp.full((b, l), jnp.inf, jnp.float32)
-    pool_exp = jnp.zeros((b, l), bool)
-    pool_ids, pool_d, pool_exp = _pool_merge(
-        pool_ids, pool_d, pool_exp, seed_ids, -seed_neg, l)
+        pool_ids = jnp.full((b, l), -1, jnp.int32)
+        pool_d = jnp.full((b, l), jnp.inf, jnp.float32)
+        pool_exp = jnp.zeros((b, l), bool)
+        pool_ids, pool_d, pool_exp = _pool_merge(
+            pool_ids, pool_d, pool_exp, seed_ids, -seed_neg, l)
 
     rows = jnp.arange(b)
     codes_i = codes.astype(jnp.int32)
 
-    if fused:
-        # --- one VMEM-resident program for the whole hop loop
-        pool_ids, pool_d, pool_exp, hops, *_ = beam_hops(
-            adj, pool_ids, pool_d, pool_exp, max_hops,
-            tables=tables, codes=codes_i, backend=inner)
-    else:
-        def step(state, _):
-            pool_ids, pool_d, pool_exp, hops = state
-            frontier_d = jnp.where(pool_exp | (pool_ids < 0), jnp.inf, pool_d)
-            j = jnp.argmin(frontier_d, axis=1)             # (B,)
-            has = jnp.isfinite(frontier_d[rows, j])
-            v = jnp.where(has, pool_ids[rows, j], 0)
-            pool_exp = pool_exp.at[rows, j].set(pool_exp[rows, j] | has)
-            nbrs = jnp.where(has[:, None], adj[v], -1)     # (B, R)
-            nd = pq_adc_rowwise(tables, codes_i[jnp.clip(nbrs, 0)],
-                                backend=inner)
-            nd = jnp.where(nbrs >= 0, nd, jnp.inf)
-            pool_ids, pool_d, pool_exp = _pool_merge(
-                pool_ids, pool_d, pool_exp, nbrs, nd, l)
-            return (pool_ids, pool_d, pool_exp, hops + has), None
+    with jax.named_scope("bamg.hop_loop"):
+        if fused:
+            # --- one VMEM-resident program for the whole hop loop
+            pool_ids, pool_d, pool_exp, hops, *_ = beam_hops(
+                adj, pool_ids, pool_d, pool_exp, max_hops,
+                tables=tables, codes=codes_i, backend=inner)
+        else:
+            def step(state, _):
+                pool_ids, pool_d, pool_exp, hops = state
+                frontier_d = jnp.where(pool_exp | (pool_ids < 0), jnp.inf,
+                                       pool_d)
+                j = jnp.argmin(frontier_d, axis=1)         # (B,)
+                has = jnp.isfinite(frontier_d[rows, j])
+                v = jnp.where(has, pool_ids[rows, j], 0)
+                pool_exp = pool_exp.at[rows, j].set(pool_exp[rows, j] | has)
+                nbrs = jnp.where(has[:, None], adj[v], -1)  # (B, R)
+                nd = pq_adc_rowwise(tables, codes_i[jnp.clip(nbrs, 0)],
+                                    backend=inner)
+                nd = jnp.where(nbrs >= 0, nd, jnp.inf)
+                pool_ids, pool_d, pool_exp = _pool_merge(
+                    pool_ids, pool_d, pool_exp, nbrs, nd, l)
+                return (pool_ids, pool_d, pool_exp, hops + has), None
 
-        (pool_ids, pool_d, pool_exp, hops), _ = jax.lax.scan(
-            step, (pool_ids, pool_d, pool_exp, jnp.zeros(b, jnp.int32)),
-            None, length=max_hops)
+            (pool_ids, pool_d, pool_exp, hops), _ = jax.lax.scan(
+                step, (pool_ids, pool_d, pool_exp, jnp.zeros(b, jnp.int32)),
+                None, length=max_hops)
 
     # --- exact re-rank of each row's pool prefix (tombstones masked here:
     # the fused hop loop never sees the mask, so this covers every backend)
-    cand = pool_ids[:, :rerank]                            # (B, C)
-    vecs = x[jnp.clip(cand, 0)]                            # (B, C, D)
-    valid = (cand >= 0) & ~tomb[jnp.clip(cand, 0)]
-    dists, ridx = l2_topk_rowwise(queries, vecs, k, valid=valid)
-    ids = jnp.take_along_axis(cand, ridx, axis=1)
-    ids = jnp.where(jnp.isfinite(dists), ids, -1)
+    with jax.named_scope("bamg.rerank"):
+        cand = pool_ids[:, :rerank]                        # (B, C)
+        vecs = x[jnp.clip(cand, 0)]                        # (B, C, D)
+        valid = (cand >= 0) & ~tomb[jnp.clip(cand, 0)]
+        dists, ridx = l2_topk_rowwise(queries, vecs, k, valid=valid)
+        ids = jnp.take_along_axis(cand, ridx, axis=1)
+        ids = jnp.where(jnp.isfinite(dists), ids, -1)
     return ids, dists, hops
 
 
@@ -228,6 +234,10 @@ class BatchedANNEngine:
         self._rerank = min(config.rerank if config.rerank is not None else l, l)
         self._n_entry = min(config.n_entry, len(cands))
         self._fault: Optional[Exception] = None
+        # the last search_batch call's hops that expanded a node, (B,)
+        # int32 on the host, and the hops its hop loop ran
+        self.last_hops: Optional[np.ndarray] = None
+        self.last_hops_run: Optional[int] = None
 
     @classmethod
     def from_index(cls, idx, config: Optional[EngineConfig] = None):
@@ -308,6 +318,9 @@ class BatchedANNEngine:
         standing `set_tombstones` mask): excluded ids stay navigable but
         never appear in the returned top-k.  Accepts an iterable of VIDs
         or a (N,) bool mask.
+
+        The call's hops per row are left in `last_hops`, and the hops its
+        hop loop ran in `last_hops_run`.
         """
         if self._fault is not None:
             raise self._fault
@@ -336,12 +349,17 @@ class BatchedANNEngine:
                     mask[ids] = True
                 extra = mask
             tomb = tomb | jnp.asarray(extra)
-        ids, dists, _ = batched_search(
+        out = batched_search(
             self.x, self.adj, self.codes, self.codebooks, self.entry_cands,
             self.entry_codes, q, tomb, k=k, l=l_eff,
             max_hops=hops, n_entry=self._n_entry,
             rerank=rerank, backend=self.config.backend)
-        return np.asarray(ids, np.int64), np.asarray(dists)
+        with telemetry.span(telemetry.DEVICE_WAIT):
+            jax.block_until_ready(out)
+        with telemetry.span(telemetry.FETCH):
+            ids, dists, self.last_hops = jax.device_get(out)
+        self.last_hops_run = hops
+        return ids.astype(np.int64), dists
 
     def memory_bytes(self) -> int:
         return sum(int(a.size) * a.dtype.itemsize

@@ -98,6 +98,10 @@ class ServeStatus:
     degraded: np.ndarray                 # (B,) bool: answer missed >=1 shard
     shards_up: int
     shards_down: tuple                   # shard indices skipped this batch
+    # hops that expanded a node per row, and hops the hop loop ran, each
+    # the mean over the live shards; None where no engine reports them
+    hops: Optional[np.ndarray] = None    # (B,)
+    hops_run: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -114,6 +118,8 @@ class _ExecState:
     all_ids: list = dataclasses.field(default_factory=list)
     all_d: list = dataclasses.field(default_factory=list)
     down: list = dataclasses.field(default_factory=list)
+    hops: list = dataclasses.field(default_factory=list)
+    hops_run: list = dataclasses.field(default_factory=list)
     ids: Optional[np.ndarray] = None
     dists: Optional[np.ndarray] = None
 
@@ -149,6 +155,9 @@ class InstructionInterpreter:
             degraded=np.full(st.b, bool(st.down)),
             shards_up=self.placement.n_shards - len(st.down),
             shards_down=tuple(st.down))
+        if st.hops:
+            status.hops = np.mean(st.hops, axis=0)
+            status.hops_run = float(np.mean(st.hops_run))
         return st.ids, st.dists, status
 
     # --- opcodes ------------------------------------------------------------
@@ -180,6 +189,10 @@ class InstructionInterpreter:
                 self.placement.record_failure(rep, e)
                 continue
             st.results[s] = (ids_s, d_s, ks)
+            hops = getattr(rep.engine, "last_hops", None)
+            if hops is not None:
+                st.hops.append(hops)
+                st.hops_run.append(rep.engine.last_hops_run)
             return
 
     def _gather(self, st: _ExecState, ins: Instruction) -> None:

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.core.engine import BAMGIndex, BAMGParams
 
+from .. import telemetry
 from ..ann_engine import BatchedANNEngine, EngineConfig
 from .instructions import InstructionInterpreter, compile_program
 from .placement import ShardPlacement
@@ -152,15 +153,17 @@ class ServeRuntime:
         marked down and its RUN retried on the next replica.  With every
         shard down the answer is all -1/+inf.  `with_status=True`
         additionally returns a `ServeStatus` whose `degraded` flags mark
-        answers that missed at least one shard.  `l`/`max_hops` shrink the
+        answers that missed at least one shard, and whose `hops` and
+        `hops_run` count each row's hop loop.  `l`/`max_hops` shrink the
         beam for this batch only (deadline-pressed micro-batches).
         `exclude` is an iterable of *global* tombstoned ids (streaming
         freshness); they are scattered to shard-local masks and never
         appear in the merged top-k.
         """
-        ids, dists, status = self.interpreter.execute(
-            self.program, queries, k, l=l, max_hops=max_hops,
-            exclude=self._scatter_exclude(exclude))
+        with telemetry.span(telemetry.STEP):
+            ids, dists, status = self.interpreter.execute(
+                self.program, queries, k, l=l, max_hops=max_hops,
+                exclude=self._scatter_exclude(exclude))
         if not with_status:
             return ids, dists
         return ids, dists, status

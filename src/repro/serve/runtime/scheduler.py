@@ -33,6 +33,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .. import telemetry
+
 
 @dataclasses.dataclass
 class Request:
@@ -72,6 +74,10 @@ class Completion:
     tier: int                  # BeamTier index it executed on
     deadline_met: bool
     degraded: bool             # shrunk beam and/or missed >=1 shard
+    round: Optional[int] = None      # formation round (the `bamg.round` id)
+    queued: Optional[float] = None   # due time -> its batch's dispatch, s
+    hops: Optional[float] = None     # hops that expanded a node for it
+    hops_run: Optional[float] = None  # hops its batch's hop loop ran
 
 
 class RequestQueue:
@@ -188,29 +194,43 @@ class Scheduler:
 
         The clock `t` advances by *measured* wall-clock service time of
         each micro-batch; arrivals are admitted whenever `t` passes them,
-        so queueing delay under overload shows up in the latencies."""
+        so queueing delay under overload shows up in the latencies: each
+        Completion's `queued` is the part before its batch was dispatched,
+        and `latency - queued` the batch's service.  Each formation round
+        is one `bamg.round` span whose `round` the Completions carry; the
+        runtime's `hops`/`hops_run` (where its status has them) are copied
+        per row."""
         reqs = sorted(requests, key=lambda r: (r.arrival, r.rid))
         if not reqs:
             return []
         if warmup:
             self.warmup(len(np.atleast_1d(reqs[0].query)))
         out: list[Completion] = []
-        t, i, n = 0.0, 0, len(reqs)
+        t, i, n, rnd = 0.0, 0, len(reqs), 0
         while i < n or len(self.queue):
-            if not len(self.queue):              # idle: jump to next arrival
-                t = max(t, reqs[i].arrival)
-            while i < n and reqs[i].arrival <= t + 1e-12:
-                self.queue.push(reqs[i])
-                i += 1
-            for tier_idx, batch in self.form_microbatches(t):
-                ids, dists, status, dt = self._execute(tier_idx, batch)
-                t += dt
-                for j, r in enumerate(batch):
-                    out.append(Completion(
-                        rid=r.rid, ids=ids[j], dists=dists[j],
-                        arrival=r.arrival, finish=t, latency=t - r.arrival,
-                        tier=tier_idx, deadline_met=t <= r.deadline,
-                        degraded=bool(status.degraded[j]) or tier_idx > 0))
+            with telemetry.span(telemetry.ROUND, round=rnd):
+                if not len(self.queue):          # idle: jump to next arrival
+                    t = max(t, reqs[i].arrival)
+                while i < n and reqs[i].arrival <= t + 1e-12:
+                    self.queue.push(reqs[i])
+                    i += 1
+                for tier_idx, batch in self.form_microbatches(t):
+                    ids, dists, status, dt = self._execute(tier_idx, batch)
+                    sent, t = t, t + dt
+                    # duck-typed runtimes may report no hops
+                    hops = getattr(status, "hops", None)
+                    hops_run = getattr(status, "hops_run", None)
+                    for j, r in enumerate(batch):
+                        out.append(Completion(
+                            rid=r.rid, ids=ids[j], dists=dists[j],
+                            arrival=r.arrival, finish=t,
+                            latency=t - r.arrival, tier=tier_idx,
+                            deadline_met=t <= r.deadline,
+                            degraded=bool(status.degraded[j]) or tier_idx > 0,
+                            round=rnd, queued=sent - r.arrival,
+                            hops=None if hops is None else float(hops[j]),
+                            hops_run=hops_run))
+            rnd += 1
         out.sort(key=lambda c: c.rid)
         return out
 
