@@ -55,6 +55,12 @@ def test_readers_on_the_tiny_run(run_tiny, monkeypatch):
     assert all(c.latency - c.queued > 0 for c in rec.done)
     assert ((hops >= 0) & (hops <= run)).all() and (hops > 0).any()
     m = r["metrics"]
+    # no TPU plane in a CPU trace: the device metrics are left out, and
+    # the program's counters and spans are read
+    assert set(m) == {"batch_fill", "step_ms", "queue_wait_ms",
+                      "hop_useful_share", "host_step_ms"}
+    assert 0 < m["hop_useful_share"]["value"] <= 1
+    assert m["host_step_ms"]["value"] > 0
     assert m["queue_wait_ms"]["value"] == pytest.approx(
         np.percentile(queued, 95) * 1e3)
     assert m["hop_useful_share"]["value"] == pytest.approx(

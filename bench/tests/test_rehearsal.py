@@ -30,12 +30,8 @@ def test_traced_run_reports_per_layer_but_no_device_metric(run_tiny):
     r = run_tiny(trace=True)
     assert r["correct"] is True
     # no TPU plane in a CPU trace: the device metrics are left out
-    assert set(r["metrics"]) == {"batch_fill", "step_ms", "queue_wait_ms",
-                                 "hop_useful_share", "host_step_ms"}
+    assert set(r["metrics"]) == {"batch_fill", "step_ms"}
     assert 0 < r["metrics"]["batch_fill"]["value"] <= 1
-    assert 0 < r["metrics"]["hop_useful_share"]["value"] <= 1
-    assert r["metrics"]["queue_wait_ms"]["value"] >= 0
-    assert r["metrics"]["host_step_ms"]["value"] > 0
     assert r["device"]["window_s"] > 0 and r["device"]["busy_s"] == 0
     assert list(r)[-1] == "checks"
 

@@ -3,24 +3,17 @@ trace whose numbers are worked out by hand.
 
 The trace has the layout a TPU run writes: a `/device:TPU:0` plane whose
 `XLA Ops` line holds the operations (its `XLA Modules` line must be
-ignored), and a host plane with the benchmark's spans and the program's.
-Times in ns:
+ignored), and a host plane with the benchmark's spans.  Times in ns:
 
     host   bench.window        [  50, 1050)
            bench.serve_batch   [  60,  600)   [ 700, 1000)
-           bamg.round          [  55,  650)   [ 690, 1020)   [1100, 1200)
-           bamg.device_wait    [  90,  410)   [ 720,  760)   [1110, 1150)
     device hop-loop kernel     [ 100,  400)
            fusion              [ 450,  500)
            copy                [ 480,  520)   (overlaps the fusion)
            fusion              [5000, 5100)   (after the window)
 
 Busy is [100, 400) + [450, 520) = 370 ns of a 1000 ns window; the idle
-gaps are [520, 1050), [50, 100) and [400, 450), and the innermost span
-over the middle of each is a `bench.serve_batch`.  The rounds inside the
-window less their device waits take 595 - 320 = 275 ns and 330 - 40 =
-290 ns, so `host_step_ms` is 282.5 ns; the third round ends after the
-window and is left out.
+gaps are [520, 1050), [50, 100) and [400, 450).
 """
 import dataclasses
 from types import SimpleNamespace
@@ -64,17 +57,9 @@ planes {{
   lines {{ id: 1 name: "python" timestamp_ns: 0
     {_event(1, 50, 1050)}
     {_event(2, 60, 600)}
-    {_event(2, 700, 1000)}
-    {_event(3, 55, 650)}
-    {_event(4, 90, 410)}
-    {_event(3, 690, 1020)}
-    {_event(4, 720, 760)}
-    {_event(3, 1100, 1200)}
-    {_event(4, 1110, 1150)} }}
+    {_event(2, 700, 1000)} }}
   event_metadata {{ key: 1 value {{ id: 1 name: "{tr.WINDOW_SPAN}" }} }}
   event_metadata {{ key: 2 value {{ id: 2 name: "{tr.STEP_SPAN}" }} }}
-  event_metadata {{ key: 3 value {{ id: 3 name: "bamg.round" }} }}
-  event_metadata {{ key: 4 value {{ id: 4 name: "bamg.device_wait" }} }}
 }}
 """
 
@@ -139,16 +124,6 @@ def test_device_metric_readers(recorded):
     assert _read("hop_loop_roofline", run) == pytest.approx(want)
     assert _read("batch_fill", run) == pytest.approx(1.0)
     assert _read("step_ms", run) == pytest.approx(400.0)
-
-
-def test_host_step_ms_reads_the_program_spans(recorded):
-    assert _read("host_step_ms", _record(recorded)) == pytest.approx(
-        282.5e-6)
-    # a program without spans, or no trace: nothing, and no error
-    bare = dataclasses.replace(recorded, spans=[
-        e for e in recorded.spans if not e.name.startswith("bamg.")])
-    assert _read("host_step_ms", _record(bare)) is None
-    assert _read("host_step_ms", _record(None)) is None
 
 
 def test_readers_return_nothing_without_a_device(recorded):
