@@ -52,8 +52,8 @@ from .chunking import map_chunks
 from .pool import pool_merge
 
 # frontier_pools backend -> the beam_hops backend the fused path pins
-# (the `fused_stream*` modes run the HBM-streaming double-buffered
-# program, for corpora whose resident footprint exceeds the VMEM budget)
+# (the `fused_stream*` modes run the HBM-streaming row-DMA program, for
+# corpora whose resident footprint exceeds the VMEM budget)
 _FUSED = {"fused": "auto", "fused_pallas": "pallas",
           "fused_interpret": "interpret", "fused_ref": "ref",
           "fused_stream": "stream", "fused_stream_interpret":
@@ -218,7 +218,7 @@ def frontier_pools(
     "fused_stream_interpret" -- the fused beam-hop kernel at width 1
     (`width` is ignored; hop count defaults to the width-1
     `default_hops`, so pass `max_hops` to bound it).  The `fused_stream*`
-    modes run the HBM-streaming double-buffered program, for build
+    modes run the HBM-streaming row-DMA program, for build
     corpora whose resident footprint exceeds the VMEM budget.
     """
     if backend != "batched" and backend not in _FUSED:

@@ -6,16 +6,16 @@ frontier select, adjacency gather, neighbor scoring, pool merge -- as a
 engine round-trips pool/frontier arrays through HBM between four XLA
 programs per hop; here one program launch serves all `max_hops` hops.
 
-TPU adaptation of each stage (no fast gather on TPU, so every gather is
-a one-hot contraction -- the `pq_adc` trick applied throughout):
+TPU adaptation of each stage:
 
 - **frontier select**: the pool is kept sorted, so the pop is the first
   unexpanded valid entry -- a masked iota min + one-hot readout, no
   argsort.
-- **adjacency / code / vector gather**: rows are pulled from the corpus
-  arrays by one-hot @ matrix MXU contractions, chunked over N
-  (`n_chunk`) so the one-hot tile, not the corpus, bounds the live
-  footprint.
+- **adjacency / code / vector gather**: resident, rows are pulled from
+  the VMEM corpus blocks by one-hot @ matrix MXU contractions (the
+  `pq_adc` trick), chunked over N (`n_chunk`) so the one-hot tile, not
+  the corpus, bounds the live footprint; streamed, each row is one DMA
+  from HBM addressed by its id (`_gather_rows_dma`).
 - **scoring**: mode="adc" inlines the `pq_adc_rowwise` one-hot LUT
   lookup against the tile's private (TB, M, K) tables; mode="l2" is the
   build frontier's dot-form exact distance vs (N, D+1) vectors carrying
@@ -38,17 +38,19 @@ corpus lives:
   see `vmem_bytes`.  A 100k-node shard at R=32, M=16 is ~20 MB, past
   most cores' VMEM.
 - **streaming** (`beam_hops_{adc,l2}_stream`): the corpus stays in HBM
-  (`memory_space=ANY`); every gather walks it in `n_chunk`-row slabs
-  DMA'd into a double-buffered VMEM scratch (`pltpu.make_async_copy`:
-  the copy for slab i+1 is issued before the one-hot tile contracts
-  slab i, so the MXU and the DMA engine overlap).  Footprint is
-  `stream_vmem_bytes` -- O(n_chunk), independent of N -- which is what
+  (`memory_space=ANY`).  Each hop moves the TB frontier ids to SMEM and
+  DMAs their TB adjacency rows into a VMEM row buffer, then does the
+  same for the TB*R neighbors' code/vector rows: every DMA of a round is
+  issued before any is waited on, so a hop waits on two rounds of row
+  DMAs (each after one small id copy) and moves bytes in proportion to
+  R, not N.  Footprint is
+  `stream_vmem_bytes` -- independent of N and n_chunk -- which is what
   lets one grid step serve a shard far larger than VMEM instead of
   requiring `serve.frontend.ShardedFrontend` to slice the corpus down
-  to fast-memory size.  The slab walk order and slab contents are
-  identical to the resident gather's chunk loop, so both modes are
-  bit-identical on every output (streaming changes timing and memory
-  traffic, never results).
+  to fast-memory size.  The rows land in the order the resident
+  gather's one-hot contraction returns them, with the same values, so
+  both modes are bit-identical on every output (streaming changes timing
+  and memory traffic, never results).
 
 Ids and flags travel as exact f32 (N < 2^24) so every stage stays on
 the VPU/MXU datapath.
@@ -124,15 +126,19 @@ def stream_vmem_bytes(n: int, r: int, *, m: int | None = None,
                       tile_b: int = 8, n_chunk: int = 2048,
                       k: int = 256) -> int:
     """Estimated VMEM footprint of one *streaming* fused grid step: the
-    resident estimate minus the corpus blocks, plus the two double-
-    buffered (2, n_chunk, R|row_w) DMA slabs, lane-padded to 128 --
-    O(n_chunk), not O(n)."""
+    resident estimate minus the corpus blocks and the (TB*R, n_chunk)
+    gather one-hot, plus the row-gather scratch (`_stream_scratch`): the
+    TB adjacency rows and TB*R codes/vector rows, lane-padded to 128, and
+    their int32 ids (the SMEM copy of the ids is not VMEM).  Independent
+    of n and of n_chunk, which the streamed gather does not use."""
     row_w, _ = _mode_dims(m, d)
     resident = vmem_bytes(n, r, m=m, d=d, l=l, max_hops=max_hops,
                           tile_b=tile_b, n_chunk=n_chunk, k=k)
     f = 4
-    slabs = 2 * n_chunk * (_lanes(r) + _lanes(row_w)) * f
-    return resident - n * (r + row_w) * f + slabs
+    onehot = tile_b * r * n_chunk * f
+    rows = tile_b * (_lanes(r) + r * _lanes(row_w)) * f
+    ids = tile_b * (_lanes(1) + _lanes(r)) * 4
+    return resident - n * (r + row_w) * f - onehot + rows + ids
 
 
 def fits_vmem(n: int, r: int, *, m: int | None = None, d: int | None = None,
@@ -201,42 +207,51 @@ def _gather_rows(ids_col, mat_ref, n: int, n_chunk: int):
                              jnp.zeros((s, c), jnp.float32))
 
 
-def _gather_rows_stream(ids_col, hbm_ref, buf, sem, n: int, n_chunk: int,
-                        width: int):
-    """`_gather_rows` with the corpus in HBM: the slab for chunk i is
-    DMA'd into one slot of the (2, n_chunk, C) VMEM scratch `buf` while
-    the one-hot tile contracts the other slot (double buffering --
-    `make_async_copy` for slab i+1 is started before the wait on slab i).
-    HBM rows are lane-padded (`_lane_pad`); the first `width` columns are
-    returned.  Same chunk order and contents as the resident gather, so
-    the f32 accumulation -- and therefore every downstream output -- is
-    bit-identical."""
-    s = ids_col.shape[0]
-    c = hbm_ref.shape[1]
-    col = _iota_f32((s, n_chunk), 1)
-    num = n // n_chunk
+def _gather_rows_dma(ids, hbm_ref, scratch, n: int):
+    """Row gather with the corpus in HBM: ids (T, K) exact-int f32 ->
+    rows (T*K, C) f32 in row-major order (row t*K + j holds corpus row
+    `ids[t, j]`, the order `_column` gives the resident gather).
 
-    def dma(slot, ci):
-        return pltpu.make_async_copy(
-            hbm_ref.at[pl.ds(ci * n_chunk, n_chunk), :],
-            buf.at[slot], sem.at[slot])
+    `hbm_ref` is the lane-padded (N, C) corpus viewed as (N*P, 128), P =
+    C / 128 (`_row_view`): Mosaic DMAs whole 128-lane rows.  `scratch` is
+    one `_row_gather_scratch` set.  The ids are clamped to [0, n) (a
+    valid id is unchanged; no DMA can leave the corpus), written as int32
+    to a VMEM block and moved to SMEM by one local DMA, so the scalar
+    core can address rows with them.  Then every row DMA of the round is
+    issued on one semaphore before any is waited on: T*K copies of one
+    corpus row each, so the bytes moved grow with K, not with n.  DMAs
+    copy values, so the rows are exactly the ones the one-hot contraction
+    of the resident gather returns."""
+    rows, id_vmem, id_smem, sem = scratch
+    t, k = ids.shape
+    p = hbm_ref.shape[0] // n
+    id_vmem[...] = jnp.clip(ids, 0.0, n - 1.0).astype(jnp.int32)
+    ids_copy = pltpu.make_async_copy(id_vmem, id_smem, sem)
+    ids_copy.start()
+    ids_copy.wait()
 
-    dma(0, 0).start()
+    def row_copy(i, v):
+        return pltpu.make_async_copy(hbm_ref.at[pl.ds(v * p, p), :],
+                                     rows.at[pl.ds(i * p, p), :], sem)
 
-    def body(ci, acc):
-        slot = jax.lax.rem(ci, 2)
+    def issue(q, c):
+        def one(j, c):
+            row_copy(q * k + j, id_smem[q, j]).start()
+            return c
+        return jax.lax.fori_loop(0, k, one, c, unroll=True)
 
-        @pl.when(ci + 1 < num)
-        def _():
-            dma(jax.lax.rem(ci + 1, 2), ci + 1).start()
+    def wait(q, c):
+        def one(j, c):
+            row_copy(q * k + j, 0).wait()
+            return c
+        return jax.lax.fori_loop(0, k, one, c, unroll=True)
 
-        dma(slot, ci).wait()
-        off = (ci * n_chunk).astype(jnp.float32)
-        onehot = (col + off == ids_col).astype(jnp.float32)
-        return acc + _onehot_dot(onehot, buf[slot])
-
-    acc = jax.lax.fori_loop(0, num, body, jnp.zeros((s, c), jnp.float32))
-    return acc[:, :width]
+    jax.lax.fori_loop(0, t, issue, 0)
+    jax.lax.fori_loop(0, t, wait, 0)
+    if p == 1:
+        return rows[...]
+    return jnp.concatenate(
+        [rows[pl.ds(j, t * k, stride=p), :] for j in range(p)], axis=1)
 
 
 def _merge_ranked(pids, pd, pexp, cids, cd, tb: int, l: int, r: int):
@@ -286,8 +301,8 @@ def _merge_ranked(pids, pd, pexp, cids, cd, tb: int, l: int, r: int):
 def _hop_loop(gather_adj, ids_ref, d_ref, exp_ref, score, outs,
               *, max_hops: int, r: int):
     """Shared hop loop; `gather_adj(v_col (TB, 1)) -> (TB, R)` pulls the
-    frontier adjacency rows (resident `pl.ds` chunks or streamed
-    HBM slabs) and `score(nbrs, valid) -> (TB, R)` closes over the
+    frontier adjacency rows (resident one-hot chunks or streamed row
+    DMAs) and `score(nbrs, valid) -> (TB, R)` closes over the
     mode-specific operands.  Writes the eight output refs in `outs`."""
     (oi_ref, od_ref, oe_ref, oh_ref, oti_ref, otd_ref,
      onx_ref, odn_ref) = outs
@@ -337,13 +352,14 @@ def _hop_loop(gather_adj, ids_ref, d_ref, exp_ref, score, outs,
 
 def _adc_score_from(gather_codes, tables, tb: int, r: int):
     """ADC scoring closure shared by the resident and streaming kernels:
-    gather the frontier neighbors' PQ codes, then the `pq_adc_rowwise`
-    one-hot LUT lookup against the tile's private (TB, M, K) tables."""
+    gather the frontier neighbors' PQ codes (`gather_codes(ids (TB, R))
+    -> (TB*R, M)`, row-major), then the `pq_adc_rowwise` one-hot LUT
+    lookup against the tile's private (TB, M, K) tables."""
     m_sub, k_cent = tables.shape[1], tables.shape[2]
     kio = jax.lax.broadcasted_iota(jnp.int32, (tb, r, k_cent), 2)
 
     def score(nbrs, valid):
-        ncodes = gather_codes(_column(jnp.maximum(nbrs, 0.0)))   # (TB*R, M)
+        ncodes = gather_codes(jnp.maximum(nbrs, 0.0))            # (TB*R, M)
         ncodes = ncodes.astype(jnp.int32).reshape(tb, r, m_sub)
         nd = jnp.zeros((tb, r), jnp.float32)
         for mi in range(m_sub):
@@ -356,11 +372,12 @@ def _adc_score_from(gather_codes, tables, tb: int, r: int):
 
 def _l2_score_from(gather_xn, q, dd: int, tb: int, r: int):
     """Exact-L2 scoring closure shared by the resident and streaming
-    kernels: gather (vector, squared-norm) rows, dot-form distance."""
+    kernels: gather (vector, squared-norm) rows (`gather_xn(ids (TB, R))
+    -> (TB*R, D+1)`, row-major), dot-form distance."""
     qn = jnp.sum(q * q, axis=1)
 
     def score(nbrs, valid):
-        rows = gather_xn(_column(jnp.maximum(nbrs, 0.0)))        # (TB*R, D+1)
+        rows = gather_xn(jnp.maximum(nbrs, 0.0))                 # (TB*R, D+1)
         rows = rows.reshape(tb, r, dd + 1)
         vecs = rows[:, :, :dd]
         n2g = rows[:, :, dd]
@@ -378,7 +395,7 @@ def _beam_adc_kernel(adj_ref, codes_ref, tables_ref, ids_ref, d_ref, exp_ref,
     tb = ids_ref.shape[0]
     r = adj_ref.shape[1]
     score = _adc_score_from(
-        lambda ids: _gather_rows(ids, codes_ref, n, n_chunk),
+        lambda ids: _gather_rows(_column(ids), codes_ref, n, n_chunk),
         tables_ref[...], tb, r)
     _hop_loop(lambda v: _gather_rows(v, adj_ref, n, n_chunk),
               ids_ref, d_ref, exp_ref, score, outs,
@@ -390,46 +407,50 @@ def _beam_l2_kernel(adj_ref, xn_ref, q_ref, ids_ref, d_ref, exp_ref,
     tb = ids_ref.shape[0]
     r = adj_ref.shape[1]
     dd = xn_ref.shape[1] - 1                     # last column = squared norm
-    score = _l2_score_from(lambda ids: _gather_rows(ids, xn_ref, n, n_chunk),
-                           q_ref[...], dd, tb, r)
+    score = _l2_score_from(
+        lambda ids: _gather_rows(_column(ids), xn_ref, n, n_chunk),
+        q_ref[...], dd, tb, r)
     _hop_loop(lambda v: _gather_rows(v, adj_ref, n, n_chunk),
               ids_ref, d_ref, exp_ref, score, outs,
               max_hops=max_hops, r=r)
 
 
+def _split_stream_refs(refs):
+    """A streamed kernel's trailing refs: its eight outputs, then the two
+    `_row_gather_scratch` sets of `_stream_scratch` (adjacency rows,
+    codes/vector rows)."""
+    return refs[:8], refs[8:12], refs[12:]
+
+
 def _beam_adc_stream_kernel(adj_ref, codes_ref, tables_ref, ids_ref, d_ref,
                             exp_ref, *outs_scratch,
-                            max_hops: int, n: int, n_chunk: int, r: int):
+                            max_hops: int, n: int, r: int):
     """ADC hop loop with adj/codes left in HBM (`memory_space=ANY`) and
-    every gather streamed through the double-buffered DMA scratch."""
-    *outs, adj_buf, adj_sem, code_buf, code_sem = outs_scratch
+    every gather a round of row DMAs (`_gather_rows_dma`)."""
+    outs, adj_scratch, code_scratch = _split_stream_refs(outs_scratch)
     tb = ids_ref.shape[0]
     m_sub = tables_ref.shape[1]
     score = _adc_score_from(
-        lambda ids: _gather_rows_stream(ids, codes_ref, code_buf, code_sem,
-                                        n, n_chunk, m_sub),
+        lambda ids: _gather_rows_dma(ids, codes_ref, code_scratch,
+                                     n)[:, :m_sub],
         tables_ref[...], tb, r)
-    _hop_loop(lambda v: _gather_rows_stream(v, adj_ref, adj_buf, adj_sem,
-                                            n, n_chunk, r),
-              ids_ref, d_ref, exp_ref, score, tuple(outs),
+    _hop_loop(lambda v: _gather_rows_dma(v, adj_ref, adj_scratch, n)[:, :r],
+              ids_ref, d_ref, exp_ref, score, outs,
               max_hops=max_hops, r=r)
 
 
 def _beam_l2_stream_kernel(adj_ref, xn_ref, q_ref, ids_ref, d_ref, exp_ref,
-                           *outs_scratch,
-                           max_hops: int, n: int, n_chunk: int, r: int):
-    """Exact-L2 hop loop with adj/vectors left in HBM and every gather
-    streamed through the double-buffered DMA scratch."""
-    *outs, adj_buf, adj_sem, xn_buf, xn_sem = outs_scratch
+                           *outs_scratch, max_hops: int, n: int, r: int):
+    """Exact-L2 hop loop with adj/vectors left in HBM and every gather a
+    round of row DMAs (`_gather_rows_dma`)."""
+    outs, adj_scratch, xn_scratch = _split_stream_refs(outs_scratch)
     tb = ids_ref.shape[0]
     dd = q_ref.shape[1]
     score = _l2_score_from(
-        lambda ids: _gather_rows_stream(ids, xn_ref, xn_buf, xn_sem,
-                                        n, n_chunk, dd + 1),
+        lambda ids: _gather_rows_dma(ids, xn_ref, xn_scratch, n)[:, :dd + 1],
         q_ref[...], dd, tb, r)
-    _hop_loop(lambda v: _gather_rows_stream(v, adj_ref, adj_buf, adj_sem,
-                                            n, n_chunk, r),
-              ids_ref, d_ref, exp_ref, score, tuple(outs),
+    _hop_loop(lambda v: _gather_rows_dma(v, adj_ref, adj_scratch, n)[:, :r],
+              ids_ref, d_ref, exp_ref, score, outs,
               max_hops=max_hops, r=r)
 
 
@@ -516,21 +537,32 @@ def beam_hops_l2_pallas(adj, xn, queries, pool_ids, pool_d, pool_exp,
     )(adj, xn, queries, pool_ids, pool_d, pool_exp)
 
 
-def _lane_pad(a):
-    """Pad the columns of an (N, C) HBM operand to a multiple of 128 lanes:
-    Mosaic DMAs only lane-aligned slabs out of HBM."""
+def _row_view(a):
+    """An (N, C) HBM operand as (N*P, 128) rows, its columns zero-padded to
+    P whole 128-lane vregs: Mosaic DMAs only 128-lane rows out of HBM, and
+    a single-row slice only of a 128-lane-wide array."""
     pad = _lanes(a.shape[1]) - a.shape[1]
-    return a if pad == 0 else jnp.pad(a, ((0, 0), (0, pad)))
+    a = a if pad == 0 else jnp.pad(a, ((0, 0), (0, pad)))
+    return a.reshape(-1, _LANES)
 
 
-def _stream_scratch(n_chunk: int, r: int, row_w: int):
-    """Double-buffered DMA scratch: (2, n_chunk, C) slab pairs + their
-    completion semaphores, for the lane-padded adjacency and codes/vector
-    gathers."""
-    return [pltpu.VMEM((2, n_chunk, _lanes(r)), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((2, n_chunk, _lanes(row_w)), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,))]
+def _row_gather_scratch(t: int, k: int, width: int):
+    """Scratch of one `_gather_rows_dma` round of t*k ids: the row buffer
+    (t*k lane-padded corpus rows, as 128-lane rows), the ids as int32 in
+    VMEM and in SMEM, and the DMA semaphore every copy of the round
+    signals."""
+    return [pltpu.VMEM((t * k * _lanes(width) // _LANES, _LANES),
+                       jnp.float32),
+            pltpu.VMEM((t, k), jnp.int32),
+            pltpu.SMEM((t, k), jnp.int32),
+            pltpu.SemaphoreType.DMA(())]
+
+
+def _stream_scratch(tile_b: int, r: int, row_w: int):
+    """The streamed kernels' scratch: one row-gather set for the TB
+    frontier adjacency rows and one for the TB*R codes/vector rows."""
+    return (_row_gather_scratch(tile_b, 1, r)
+            + _row_gather_scratch(tile_b, r, row_w))
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "tile_b", "n_chunk",
@@ -540,18 +572,20 @@ def beam_hops_adc_stream(adj, codes, tables, pool_ids, pool_d, pool_exp,
                          interpret: bool = False):
     """`beam_hops_adc_pallas` with adj/codes streamed from HBM: the corpus
     operands get `memory_space=ANY` block specs (never staged into VMEM by
-    the pipeline) and each gather DMA-copies `n_chunk`-row slabs into a
-    double-buffered VMEM scratch.  Bit-identical outputs to the resident
-    kernel at every config; VMEM footprint is `stream_vmem_bytes` --
-    independent of N, so shards far larger than VMEM serve from one grid
-    step."""
+    the pipeline) and each hop DMA-copies the TB frontier adjacency rows,
+    then the TB*R neighbor code rows, into VMEM row buffers.  Bit-
+    identical outputs to the resident kernel at every config; VMEM
+    footprint is `stream_vmem_bytes` -- independent of N, so shards far
+    larger than VMEM serve from one grid step.  `n_chunk` is only the
+    row multiple N must have (`ops.beam_hops` pads to it); the gather
+    does not depend on it."""
     b, l = pool_ids.shape
     n = adj.shape[0]
     _check_tiling(b, tile_b, n, n_chunk)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_beam_adc_stream_kernel, max_hops=max_hops, n=n,
-                          n_chunk=n_chunk, r=adj.shape[1]),
+                          r=adj.shape[1]),
         grid=(b // tile_b,),
         in_specs=[
             any_spec,
@@ -563,9 +597,9 @@ def beam_hops_adc_stream(adj, codes, tables, pool_ids, pool_d, pool_exp,
         ],
         out_specs=_out_specs(tile_b, l, max_hops),
         out_shape=_out_shapes(b, l, max_hops),
-        scratch_shapes=_stream_scratch(n_chunk, adj.shape[1], codes.shape[1]),
+        scratch_shapes=_stream_scratch(tile_b, adj.shape[1], codes.shape[1]),
         interpret=interpret,
-    )(_lane_pad(adj), _lane_pad(codes), tables, pool_ids, pool_d, pool_exp)
+    )(_row_view(adj), _row_view(codes), tables, pool_ids, pool_d, pool_exp)
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "tile_b", "n_chunk",
@@ -573,8 +607,8 @@ def beam_hops_adc_stream(adj, codes, tables, pool_ids, pool_d, pool_exp,
 def beam_hops_l2_stream(adj, xn, queries, pool_ids, pool_d, pool_exp,
                         max_hops: int, tile_b: int = 8, n_chunk: int = 2048,
                         interpret: bool = False):
-    """`beam_hops_l2_pallas` with adj/vectors streamed from HBM through
-    the double-buffered DMA scratch; same contract and bit-identical
+    """`beam_hops_l2_pallas` with adj/vectors streamed from HBM by row
+    DMAs, as `beam_hops_adc_stream`; same contract and bit-identical
     outputs, `stream_vmem_bytes` footprint."""
     b, l = pool_ids.shape
     n = adj.shape[0]
@@ -582,7 +616,7 @@ def beam_hops_l2_stream(adj, xn, queries, pool_ids, pool_d, pool_exp,
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_beam_l2_stream_kernel, max_hops=max_hops, n=n,
-                          n_chunk=n_chunk, r=adj.shape[1]),
+                          r=adj.shape[1]),
         grid=(b // tile_b,),
         in_specs=[
             any_spec,
@@ -594,6 +628,6 @@ def beam_hops_l2_stream(adj, xn, queries, pool_ids, pool_d, pool_exp,
         ],
         out_specs=_out_specs(tile_b, l, max_hops),
         out_shape=_out_shapes(b, l, max_hops),
-        scratch_shapes=_stream_scratch(n_chunk, adj.shape[1], xn.shape[1]),
+        scratch_shapes=_stream_scratch(tile_b, adj.shape[1], xn.shape[1]),
         interpret=interpret,
-    )(_lane_pad(adj), _lane_pad(xn), queries, pool_ids, pool_d, pool_exp)
+    )(_row_view(adj), _row_view(xn), queries, pool_ids, pool_d, pool_exp)

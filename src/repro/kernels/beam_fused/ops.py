@@ -14,10 +14,12 @@ backend:
 - "pallas" (TPU) / "interpret" (CPU-validated kernel): the VMEM-resident
   program -- the corpus must fit the `vmem_bytes` budget;
 - "stream" (TPU) / "stream_interpret" (CPU-validated): the HBM-streaming
-  program -- corpus arrays stay in HBM and every gather DMA-walks them
-  in double-buffered `n_chunk` slabs (`stream_vmem_bytes` footprint,
-  independent of N).  Bit-identical to the resident program at every
-  config; the oracle for both is `beam_hops_ref`;
+  program -- corpus arrays stay in HBM and each hop DMAs only the rows
+  it reads: TB adjacency rows, then TB*R code/vector rows
+  (`stream_vmem_bytes` footprint, independent of N; `n_chunk` is only
+  the row multiple the corpus is padded to).  Bit-identical to the
+  resident program at every config; the oracle for both is
+  `beam_hops_ref`;
 - "ref": pure jnp scan, bit-identical to the unfused serve hop loop;
 - "auto": on TPU, "pallas" when the resident footprint fits
   `vmem_budget_bytes()` else "stream"; "ref" elsewhere.

@@ -17,8 +17,9 @@ The merge is `pool_merge_ranked` -- bit-identical to the serve engine's
 `pool_merge` but sort-free, which is the form the Pallas kernel inlines
 (and already ~2x cheaper than the concat-double-argsort under XLA CPU).
 This oracle anchors *both* Pallas execution modes: the VMEM-resident
-program and the HBM-streaming program gather identical slab contents in
-identical order, so resident == streaming == ref on every output.
+program's one-hot gather and the HBM-streaming program's row DMAs return
+identical rows in identical order, so resident == streaming == ref on
+every output.
 Beyond the final pool, every hop emits its frontier pick (the trace the
 build frontier returns as its visited set), and the loop ends with the
 *next* frontier pick and a done mask, so callers chain hop programs

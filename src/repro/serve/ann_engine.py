@@ -27,9 +27,9 @@ lifetime of the server:
   Pallas program (`repro.kernels.beam_fused`: frontier select, one-hot
   adjacency/code gathers, inlined rowwise ADC, and a sort-free ranked pool
   merge per hop) -- bit-identical pool ids by construction, no per-hop
-  HBM round-trip.  `fused_stream*` keeps the corpus in HBM and streams it
-  through double-buffered DMA slabs, so one engine serves shards larger
-  than VMEM (bit-identical to the resident fused path); `backend="auto"`
+  HBM round-trip.  `fused_stream*` keeps the corpus in HBM and DMAs only
+  the rows each hop reads, so one engine serves shards larger than VMEM
+  (bit-identical to the resident fused path); `backend="auto"`
   picks resident vs streaming on TPU via the `beam_fused.vmem_bytes`
   estimator.  The unfused path stays as the oracle, its per-stage
   kernels (`pq_adc`, `pq_adc_rowwise`) dispatched on the same backend knob.
@@ -121,7 +121,7 @@ class EngineConfig:
     #   "fused_pallas"/"fused_interpret"/"fused_ref"   fused loop pinned
     #                      to one beam_hops backend (parity/CI)
     #   "fused_stream"/"fused_stream_interpret"   the HBM-streaming fused
-    #                      loop (double-buffered DMA corpus slabs;
+    #                      loop (row DMAs from the HBM corpus;
     #                      bit-identical pools to the resident fused path)
     backend: str = "auto"
 

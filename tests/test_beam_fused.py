@@ -168,7 +168,7 @@ def test_beam_hops_exhausts_and_reports_done():
     assert (tail == -1).all()
 
 
-# --- streaming mode: HBM-resident corpus, double-buffered DMA gathers --------
+# --- streaming mode: HBM-resident corpus, row-DMA gathers --------------------
 
 def test_beam_hops_stream_interpret_matches_ref_adc():
     adj, x, codes, tables, _, pi, pd, pe = _graph()
@@ -210,6 +210,58 @@ def test_beam_hops_stream_bitwise_matches_resident(n_chunk):
                        backend="stream_interpret", n_chunk=n_chunk, **kw)
     for got, want in zip(stream, res):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _edge_graph(mode, d=6, n=300, r=8, m=4, k=16, b=8, l=12, seed=11):
+    """A corpus of the row gather's edge cases: the last row (id n-1) in
+    seeds and adjacency, rows whose adjacency is all -1, pool rows with
+    no frontier from the start or after one hop, beside rows that keep
+    one, in every 4-row tile; n is no multiple of the 128-row chunk, so
+    `beam_hops` pads the corpus."""
+    rng = np.random.default_rng(seed)
+    adj = rng.integers(0, n, (n, r)).astype(np.int32)
+    adj[rng.random((n, r)) < 0.2] = -1
+    dead = np.arange(10, 20)                          # adjacency all -1
+    adj[dead] = -1
+    adj[rng.choice(n, 40, replace=False), 0] = n - 1
+    adj[n - 1] = rng.integers(0, n, r)
+    pool_ids = np.full((b, l), -1, np.int32)
+    pool_d = np.full((b, l), np.inf, np.float32)
+    seeds = {0: [n - 1, 3, 7], 2: [n - 1], 3: [dead[0]], 4: [5, n - 1],
+             6: [dead[1], dead[2]], 7: [n - 2, 1]}     # rows 1, 5: empty
+    for row, ids in seeds.items():
+        pool_ids[row, :len(ids)] = ids
+        pool_d[row, :len(ids)] = np.sort(rng.random(len(ids)))
+    args = (jnp.asarray(adj), jnp.asarray(pool_ids), jnp.asarray(pool_d),
+            jnp.zeros((b, l), bool))
+    if mode == "adc":
+        return args, dict(
+            codes=jnp.asarray(rng.integers(0, k, (n, m)).astype(np.int32)),
+            tables=jnp.asarray(rng.random((b, m, k)).astype(np.float32)))
+    x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    return args, dict(x=x, n2=jnp.sum(x * x, axis=1), queries=jnp.asarray(
+        rng.normal(size=(b, d)).astype(np.float32)))
+
+
+@pytest.mark.parametrize("mode, d", (("adc", None), ("l2", 6), ("l2", 130)))
+def test_beam_hops_stream_row_gather_edge_cases_bitwise(mode, d):
+    """The streamed row gather is bit-identical to the resident one-hot
+    gather and to `ref` on the gather's edge cases (see `_edge_graph`);
+    d=130 gives (D+1)-wide rows two 128-lane vregs wide."""
+    args, ops = _edge_graph(mode, d=d)
+    hops = 12
+    ref = beam_hops(*args, hops, backend="ref", **ops)
+    res = beam_hops(*args, hops, backend="interpret", tile_b=4, n_chunk=128,
+                    **ops)
+    stream = beam_hops(*args, hops, backend="stream_interpret", tile_b=4,
+                       n_chunk=128, **ops)
+    hops_used = np.asarray(ref[3])
+    assert hops_used[1] == hops_used[5] == 0           # never a frontier
+    assert hops_used[3] == 1 and hops_used.max() == hops
+    assert (np.asarray(ref[4]) == args[0].shape[0] - 1).any()
+    for got, want_res, want_ref in zip(stream, res, ref):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want_res))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want_ref))
 
 
 def test_beam_hops_rejects_unknown_backend():
@@ -257,6 +309,27 @@ def test_vmem_estimator_sanity():
         bf.vmem_bytes(1000, 8)
     with pytest.raises(ValueError, match="exactly one"):
         bf.vmem_bytes(1000, 8, m=4, d=16)
+
+
+@pytest.mark.parametrize("dims, row_w", ((dict(m=16), 16),
+                                         (dict(d=130), 131)))
+def test_stream_vmem_bytes_counts_the_row_gather_scratch(dims, row_w):
+    """The streaming estimate is the resident one without the corpus and
+    the gather one-hot, plus the streamed kernels' VMEM scratch as
+    declared (rows and ids lane-padded to 128 lanes); n_chunk no longer
+    moves it."""
+    from repro.kernels.beam_fused import kernel as bk
+    n, r, tb, nc = 4096, 32, 8, 1024
+    kw = dict(l=64, max_hops=32, tile_b=tb, **dims)
+    rest = (bk.vmem_bytes(n, r, n_chunk=nc, **kw) - n * (r + row_w) * 4
+            - tb * r * nc * 4)
+    declared = sum(
+        s.shape[0] * bk._lanes(s.shape[1]) * s.dtype.itemsize
+        for s in bk._stream_scratch(tb, r, row_w)
+        if str(s.memory_space) == "vmem")
+    assert bk.stream_vmem_bytes(n, r, n_chunk=nc, **kw) == rest + declared
+    assert bk.stream_vmem_bytes(n, r, n_chunk=64, **kw) == \
+        bk.stream_vmem_bytes(n, r, n_chunk=8192, **kw)
 
 
 def test_vmem_budget_env_override(monkeypatch):

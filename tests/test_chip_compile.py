@@ -115,3 +115,45 @@ def test_batched_search_fused_stream_compiles_at_1m(spec, m, l, hops):
     # lane-padded streaming copies dominate the temporaries
     assert mem.argument_size_in_bytes < 2 ** 30
     assert mem.temp_size_in_bytes < 4 * 2 ** 30
+
+
+def _stream_scratch_avals(s, n):
+    """The VMEM/SMEM scratch the streamed ADC kernel declares at the
+    sift1m cell's widths over an n-row shard."""
+    from repro.kernels.beam_fused import kernel as bk
+    b, l, m = 64, 256, 64
+    jaxpr = jax.make_jaxpr(functools.partial(
+        bk.beam_hops_adc_stream, max_hops=l))(
+            s((n, R)), s((n, m)), s((b, m, K)), s((b, l)), s((b, l)),
+            s((b, l)))
+    (call,) = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name ==
+               "pallas_call"]
+    kernel = call.params["jaxpr"]
+    n_scratch = call.params["grid_mapping"].num_scratch_operands
+    return [str(v.aval) for v in kernel.invars[-n_scratch:]]
+
+
+def _eqns(jaxpr):
+    """Every equation of `jaxpr` and of the jitted calls inside it."""
+    for e in jaxpr.eqns:
+        yield e
+        sub = e.params.get("jaxpr")
+        if e.primitive.name != "pallas_call" and sub is not None:
+            yield from _eqns(getattr(sub, "jaxpr", sub))
+
+
+def test_adc_stream_compiles_at_cell_widths_and_scratch_ignores_n(spec):
+    """The streamed ADC hop loop compiles at the sift1m cell's widths
+    (n=65,536, R=32, M=64, B=64, l = max_hops = 256), and its scratch is
+    the same at 65,536 and 2^20 rows: the row gather does not grow with
+    the shard."""
+    from repro.kernels.beam_fused import kernel as bk
+    s = spec
+    n, b, l, m = 65_536, 64, 256, 64
+    compiled = bk.beam_hops_adc_stream.lower(
+        s((n, R)), s((n, m)), s((b, m, K)), s((b, l)), s((b, l)),
+        s((b, l)), l).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    small = _stream_scratch_avals(s, n)
+    assert len(small) == 8
+    assert small == _stream_scratch_avals(s, 2 ** 20)
