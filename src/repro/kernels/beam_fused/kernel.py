@@ -17,7 +17,16 @@ TPU adaptation of each stage:
   the corpus, bounds the live footprint; streamed, each row is one DMA
   from HBM addressed by its id (`_gather_rows_dma`).
 - **scoring**: mode="adc" inlines the `pq_adc_rowwise` one-hot LUT
-  lookup against the tile's private (TB, M, K) tables; mode="l2" is the
+  lookup against the tile's private (TB, M, K) tables
+  (`pq_adc.kernel.adc_rowwise_part`).  A 128-lane part of at most 64
+  sub-spaces is scored as one unrolled group (M=64); a wider part is
+  written sub-space-major to a (128, TB*R) scratch and scored 32
+  sub-spaces a `fori_loop` iteration, each group's table slice read from
+  the tables ref, so one group's (TB, R, K) one-hots are live at a time.
+  The streamed kernel needs 9.10 MiB of scoped VMEM at M=64, 7.07 MiB at
+  128 and 6.95 MiB at 240 (B=64, L = max_hops = 256, R=32; the form that
+  unrolled every sub-space needed 9.60 MiB at 64, ran out at 128 and
+  asked 31.1 MB at 240); mode="l2" is the
   build frontier's dot-form exact distance vs (N, D+1) vectors carrying
   their squared norms in the last column.
 - **merge**: `pool_merge_ranked` verbatim -- lexicographic (dist, id)
@@ -43,7 +52,9 @@ corpus lives:
   same for the TB*R neighbors' code/vector rows: every DMA of a round is
   issued before any is waited on, so a hop waits on two rounds of row
   DMAs (each after one small id copy) and moves bytes in proportion to
-  R, not N.  Footprint is
+  R, not N.  ADC code rows wider than 128 lanes (M > 128) come one
+  128-lane part a round, each part scored before the next is fetched,
+  so the code row buffer is (TB*R, 128) at any M.  The footprint is
   `stream_vmem_bytes` -- independent of N and n_chunk -- which is what
   lets one grid step serve a shard far larger than VMEM instead of
   requiring `serve.frontend.ShardedFrontend` to slice the corpus down
@@ -64,6 +75,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.pq_adc.kernel import (adc_rowwise_part, code_parts,
+                                         subspace_scratch)
 
 _SENT = float(2 ** 31)   # f32 id sentinel: -1 ids rank last, like pool_merge
 _LANES = 128             # f32 vreg width: VMEM/HBM rows pad to it
@@ -103,15 +117,17 @@ def vmem_bytes(n: int, r: int, *, m: int | None = None, d: int | None = None,
     width, k the PQ centroid count.  Terms: the VMEM-resident corpus
     blocks (the part streaming eliminates), the per-tile private
     operands (ADC tables / query tile), the (TB*R, n_chunk) gather
-    one-hot, the (TB, R, K) score one-hot (adc), the merge rank/scatter
-    tensors, and the pool + trace state.
+    one-hot, the scoring scratch (adc: one (TB, R, K) LUT one-hot and the
+    (128, TB*R) sub-space-major codes, whatever M is), the merge
+    rank/scatter tensors, and the pool + trace state.
     """
     row_w, dd = _mode_dims(m, d)
     f = 4
     corpus = n * (r + row_w) * f
     if m is not None:
         private = tile_b * m * k * f               # (TB, M, K) ADC tables
-        score = tile_b * r * k * f                 # (TB, R, K) LUT one-hot
+        score = (tile_b * r * k * f                # (TB, R, K) LUT one-hot
+                 + _LANES * _lanes(tile_b * r) * 4)  # `subspace_scratch`
     else:
         private = tile_b * dd * f                  # (TB, D) query tile
         score = tile_b * r * (dd + 1) * f          # gathered rows + dots
@@ -129,14 +145,17 @@ def stream_vmem_bytes(n: int, r: int, *, m: int | None = None,
     resident estimate minus the corpus blocks and the (TB*R, n_chunk)
     gather one-hot, plus the row-gather scratch (`_stream_scratch`): the
     TB adjacency rows and TB*R codes/vector rows, lane-padded to 128, and
-    their int32 ids (the SMEM copy of the ids is not VMEM).  Independent
-    of n and of n_chunk, which the streamed gather does not use."""
+    their int32 ids (the SMEM copy of the ids is not VMEM).  ADC code
+    rows arrive one 128-lane part at a time, so their buffer is 128 lanes
+    wide at any M.  Independent of n and of n_chunk, which the streamed
+    gather does not use."""
     row_w, _ = _mode_dims(m, d)
     resident = vmem_bytes(n, r, m=m, d=d, l=l, max_hops=max_hops,
                           tile_b=tile_b, n_chunk=n_chunk, k=k)
     f = 4
     onehot = tile_b * r * n_chunk * f
-    rows = tile_b * (_lanes(r) + r * _lanes(row_w)) * f
+    gathered = min(row_w, _LANES) if m is not None else row_w
+    rows = tile_b * (_lanes(r) + r * _lanes(gathered)) * f
     ids = tile_b * (_lanes(1) + _lanes(r)) * 4
     return resident - n * (r + row_w) * f - onehot + rows + ids
 
@@ -207,32 +226,41 @@ def _gather_rows(ids_col, mat_ref, n: int, n_chunk: int):
                              jnp.zeros((s, c), jnp.float32))
 
 
-def _gather_rows_dma(ids, hbm_ref, scratch, n: int):
-    """Row gather with the corpus in HBM: ids (T, K) exact-int f32 ->
-    rows (T*K, C) f32 in row-major order (row t*K + j holds corpus row
-    `ids[t, j]`, the order `_column` gives the resident gather).
-
-    `hbm_ref` is the lane-padded (N, C) corpus viewed as (N*P, 128), P =
-    C / 128 (`_row_view`): Mosaic DMAs whole 128-lane rows.  `scratch` is
-    one `_row_gather_scratch` set.  The ids are clamped to [0, n) (a
-    valid id is unchanged; no DMA can leave the corpus), written as int32
-    to a VMEM block and moved to SMEM by one local DMA, so the scalar
-    core can address rows with them.  Then every row DMA of the round is
-    issued on one semaphore before any is waited on: T*K copies of one
-    corpus row each, so the bytes moved grow with K, not with n.  DMAs
-    copy values, so the rows are exactly the ones the one-hot contraction
-    of the resident gather returns."""
-    rows, id_vmem, id_smem, sem = scratch
-    t, k = ids.shape
-    p = hbm_ref.shape[0] // n
+def _stage_ids(ids, scratch, n: int) -> None:
+    """Clamp ids (T, K) exact-int f32 to [0, n) (a valid id is unchanged;
+    no DMA can leave the corpus), write them as int32 to the VMEM block of
+    `scratch` (one `_row_gather_scratch` set) and move them to its SMEM
+    block by one local DMA, so the scalar core can address rows with
+    them."""
+    _, id_vmem, id_smem, sem = scratch
     id_vmem[...] = jnp.clip(ids, 0.0, n - 1.0).astype(jnp.int32)
     ids_copy = pltpu.make_async_copy(id_vmem, id_smem, sem)
     ids_copy.start()
     ids_copy.wait()
 
+
+def _dma_rows(hbm_ref, scratch, n: int, part: int | None = None):
+    """One round of row DMAs for the ids `_stage_ids` left in `scratch`:
+    rows (T*K, C) f32 in row-major order (row t*K + j holds corpus row
+    `ids[t, j]`, the order `_column` gives the resident gather).
+
+    `hbm_ref` is the lane-padded (N, C) corpus viewed as (N*P, 128), P =
+    C / 128 (`_row_view`): Mosaic DMAs whole 128-lane rows.  With `part`
+    given, each id moves only its row's 128-lane part `part` and the
+    result is (T*K, 128).  Every DMA of the round is issued on one
+    semaphore before any is waited on: T*K copies of one corpus row each,
+    so the bytes moved grow with K, not with n.  DMAs copy values, so the
+    rows are exactly the ones the one-hot contraction of the resident
+    gather returns."""
+    rows, _, id_smem, sem = scratch
+    t, k = id_smem.shape
+    p = hbm_ref.shape[0] // n
+    first, width = (0, p) if part is None else (part, 1)
+
     def row_copy(i, v):
-        return pltpu.make_async_copy(hbm_ref.at[pl.ds(v * p, p), :],
-                                     rows.at[pl.ds(i * p, p), :], sem)
+        return pltpu.make_async_copy(
+            hbm_ref.at[pl.ds(v * p + first, width), :],
+            rows.at[pl.ds(i * width, width), :], sem)
 
     def issue(q, c):
         def one(j, c):
@@ -248,10 +276,18 @@ def _gather_rows_dma(ids, hbm_ref, scratch, n: int):
 
     jax.lax.fori_loop(0, t, issue, 0)
     jax.lax.fori_loop(0, t, wait, 0)
-    if p == 1:
-        return rows[...]
+    if width == 1:
+        return rows[pl.ds(0, t * k), :]
     return jnp.concatenate(
         [rows[pl.ds(j, t * k, stride=p), :] for j in range(p)], axis=1)
+
+
+def _gather_rows_dma(ids, hbm_ref, scratch, n: int):
+    """Row gather with the corpus in HBM: ids (T, K) exact-int f32 ->
+    rows (T*K, C) f32, every 128-lane part of each row in one round
+    (`_stage_ids`, then `_dma_rows`)."""
+    _stage_ids(ids, scratch, n)
+    return _dma_rows(hbm_ref, scratch, n)
 
 
 def _merge_ranked(pids, pd, pexp, cids, cd, tb: int, l: int, r: int):
@@ -350,21 +386,24 @@ def _hop_loop(gather_adj, ids_ref, d_ref, exp_ref, score, outs,
     odn_ref[...] = (~has).astype(jnp.int32)[:, None]
 
 
-def _adc_score_from(gather_codes, tables, tb: int, r: int):
+def _adc_score_from(gather_codes, tables_ref, ct_ref, tb: int, r: int):
     """ADC scoring closure shared by the resident and streaming kernels:
-    gather the frontier neighbors' PQ codes (`gather_codes(ids (TB, R))
-    -> (TB*R, M)`, row-major), then the `pq_adc_rowwise` one-hot LUT
-    lookup against the tile's private (TB, M, K) tables."""
-    m_sub, k_cent = tables.shape[1], tables.shape[2]
-    kio = jax.lax.broadcasted_iota(jnp.int32, (tb, r, k_cent), 2)
+    `gather_codes(ids (TB, R))` readies the frontier neighbors' PQ codes
+    and returns `part(m0, w) -> (TB*R, >= w)` f32, their sub-spaces
+    m0 .. m0+w-1 (row-major), for each 128-lane part of the codes
+    (`pq_adc.kernel.code_parts`); each part is scored by
+    `adc_rowwise_part`, the `pq_adc_rowwise` one-hot lookup against the
+    tile's private (TB, M, K) tables, a group of sub-spaces at a time
+    (through the (128, TB*R) scratch `ct_ref` when a part is more than
+    one group)."""
+    m_sub = tables_ref.shape[1]
 
     def score(nbrs, valid):
-        ncodes = gather_codes(jnp.maximum(nbrs, 0.0))            # (TB*R, M)
-        ncodes = ncodes.astype(jnp.int32).reshape(tb, r, m_sub)
+        part = gather_codes(jnp.maximum(nbrs, 0.0))
         nd = jnp.zeros((tb, r), jnp.float32)
-        for mi in range(m_sub):
-            onehot = (kio == ncodes[:, :, mi:mi + 1]).astype(jnp.float32)
-            nd = nd + jnp.sum(onehot * tables[:, mi:mi + 1, :], axis=2)
+        for m0, w in code_parts(m_sub):
+            codes = part(m0, w)[:, :w].astype(jnp.int32)         # (TB*R, w)
+            nd = adc_rowwise_part(nd, codes, ct_ref, tables_ref, m0)
         return jnp.where(valid, nd, jnp.inf)
 
     return score
@@ -391,12 +430,16 @@ def _l2_score_from(gather_xn, q, dd: int, tb: int, r: int):
 
 
 def _beam_adc_kernel(adj_ref, codes_ref, tables_ref, ids_ref, d_ref, exp_ref,
-                     *outs, max_hops: int, n: int, n_chunk: int):
+                     *outs_scratch, max_hops: int, n: int, n_chunk: int):
+    outs, (ct_ref,) = outs_scratch[:8], outs_scratch[8:]
     tb = ids_ref.shape[0]
     r = adj_ref.shape[1]
-    score = _adc_score_from(
-        lambda ids: _gather_rows(_column(ids), codes_ref, n, n_chunk),
-        tables_ref[...], tb, r)
+
+    def gather_codes(ids):
+        codes = _gather_rows(_column(ids), codes_ref, n, n_chunk)
+        return lambda m0, w: codes[:, m0:m0 + w]
+
+    score = _adc_score_from(gather_codes, tables_ref, ct_ref, tb, r)
     _hop_loop(lambda v: _gather_rows(v, adj_ref, n, n_chunk),
               ids_ref, d_ref, exp_ref, score, outs,
               max_hops=max_hops, r=r)
@@ -418,22 +461,28 @@ def _beam_l2_kernel(adj_ref, xn_ref, q_ref, ids_ref, d_ref, exp_ref,
 def _split_stream_refs(refs):
     """A streamed kernel's trailing refs: its eight outputs, then the two
     `_row_gather_scratch` sets of `_stream_scratch` (adjacency rows,
-    codes/vector rows)."""
-    return refs[:8], refs[8:12], refs[12:]
+    codes/vector rows), then any scratch of its scoring."""
+    return refs[:8], refs[8:12], refs[12:16], refs[16:]
 
 
 def _beam_adc_stream_kernel(adj_ref, codes_ref, tables_ref, ids_ref, d_ref,
                             exp_ref, *outs_scratch,
                             max_hops: int, n: int, r: int):
     """ADC hop loop with adj/codes left in HBM (`memory_space=ANY`) and
-    every gather a round of row DMAs (`_gather_rows_dma`)."""
-    outs, adj_scratch, code_scratch = _split_stream_refs(outs_scratch)
+    every gather a round of row DMAs: the frontier adjacency rows, then
+    the neighbors' code rows one 128-lane part a round, each part scored
+    before the next is fetched, so the code row buffer holds one part
+    whatever M is."""
+    outs, adj_scratch, code_scratch, (ct_ref,) = _split_stream_refs(
+        outs_scratch)
     tb = ids_ref.shape[0]
-    m_sub = tables_ref.shape[1]
-    score = _adc_score_from(
-        lambda ids: _gather_rows_dma(ids, codes_ref, code_scratch,
-                                     n)[:, :m_sub],
-        tables_ref[...], tb, r)
+
+    def gather_codes(ids):
+        _stage_ids(ids, code_scratch, n)
+        return lambda m0, w: _dma_rows(codes_ref, code_scratch, n,
+                                       part=m0 // _LANES)
+
+    score = _adc_score_from(gather_codes, tables_ref, ct_ref, tb, r)
     _hop_loop(lambda v: _gather_rows_dma(v, adj_ref, adj_scratch, n)[:, :r],
               ids_ref, d_ref, exp_ref, score, outs,
               max_hops=max_hops, r=r)
@@ -443,7 +492,7 @@ def _beam_l2_stream_kernel(adj_ref, xn_ref, q_ref, ids_ref, d_ref, exp_ref,
                            *outs_scratch, max_hops: int, n: int, r: int):
     """Exact-L2 hop loop with adj/vectors left in HBM and every gather a
     round of row DMAs (`_gather_rows_dma`)."""
-    outs, adj_scratch, xn_scratch = _split_stream_refs(outs_scratch)
+    outs, adj_scratch, xn_scratch, _ = _split_stream_refs(outs_scratch)
     tb = ids_ref.shape[0]
     dd = q_ref.shape[1]
     score = _l2_score_from(
@@ -503,6 +552,7 @@ def beam_hops_adc_pallas(adj, codes, tables, pool_ids, pool_d, pool_exp,
         ],
         out_specs=_out_specs(tile_b, l, max_hops),
         out_shape=_out_shapes(b, l, max_hops),
+        scratch_shapes=[subspace_scratch(tile_b * adj.shape[1])],
         interpret=interpret,
     )(adj, codes, tables, pool_ids, pool_d, pool_exp)
 
@@ -597,7 +647,9 @@ def beam_hops_adc_stream(adj, codes, tables, pool_ids, pool_d, pool_exp,
         ],
         out_specs=_out_specs(tile_b, l, max_hops),
         out_shape=_out_shapes(b, l, max_hops),
-        scratch_shapes=_stream_scratch(tile_b, adj.shape[1], codes.shape[1]),
+        scratch_shapes=(_stream_scratch(tile_b, adj.shape[1],
+                                        min(codes.shape[1], _LANES))
+                        + [subspace_scratch(tile_b * adj.shape[1])]),
         interpret=interpret,
     )(_row_view(adj), _row_view(codes), tables, pool_ids, pool_d, pool_exp)
 

@@ -6,8 +6,8 @@ row, score the neighbors, merge into the sorted (B, L) pool, count the
 hop.  Scoring comes in the two flavors the two consumers need:
 
 - ``mode="adc"``: PQ table lookups over gathered neighbor codes, the
-  serving engine's estimate (`pq_adc_rowwise_ref`, bit-identical to the
-  historical `_adc_gather` take_along_axis path);
+  serving engine's estimate (`pq_adc_rowwise_ref`: the f32 sum over the
+  sub-spaces in order, as the kernels add them);
 - ``mode="l2"``: exact squared L2 in dot form with precomputed corpus
   norms and a >=0 clamp, bit-identical to the construction frontier's
   ``score`` (`repro.build.frontier`), so the batched build can run the

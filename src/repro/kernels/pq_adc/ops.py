@@ -53,8 +53,8 @@ def pq_adc_rowwise(tables: jnp.ndarray, cand_codes: jnp.ndarray,
     returns (B, R) float32
 
     Same backend matrix as `pq_adc`: "pallas" (TPU), "interpret"
-    (CPU-validated kernel), "ref" (pure jnp, bit-identical to the
-    historical take_along_axis path); "auto" = pallas on TPU else ref.
+    (CPU-validated kernel), "ref" (pure jnp, the same ordered sum over M
+    bit for bit); "auto" = pallas on TPU else ref.
     """
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "ref"

@@ -153,7 +153,8 @@ def batched_search(x, adj, codes, codebooks, entry_cands, entry_codes,
 
     # --- query-sensitive entry selection: pq_adc over the candidate pool
     with jax.named_scope("bamg.entry"):
-        tables = _adc_tables(queries, codebooks)           # (B, M, K)
+        with jax.named_scope("bamg.adc_tables"):
+            tables = _adc_tables(queries, codebooks)       # (B, M, K)
         ed = pq_adc(tables, entry_codes, backend=stage)    # (B, E)
         seed_neg, seed_idx = jax.lax.top_k(-ed, n_entry)
         seed_ids = entry_cands[seed_idx].astype(jnp.int32)  # (B, n_entry)

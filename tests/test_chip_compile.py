@@ -3,8 +3,9 @@
 Interpret mode (the rest of the suite) cannot see what Mosaic, the TPU
 kernel compiler, refuses: unaligned slices, lowerings it lacks, more VMEM
 than a core has.  Here each `pallas_call` of the serve path, and the whole
-`batched_search` step at N=1M, is lowered and compiled for a described
-`v5e:2x2` topology at serving widths.  Nothing runs.
+`batched_search` step at N=1M and at the gist1m cell's widths (d=960, PQ
+M=240), is lowered and compiled for a described `v5e:2x2` topology at
+serving widths.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every pytest-xdist worker
@@ -117,11 +118,11 @@ def test_batched_search_fused_stream_compiles_at_1m(spec, m, l, hops):
     assert mem.temp_size_in_bytes < 4 * 2 ** 30
 
 
-def _stream_scratch_avals(s, n):
+def _stream_scratch_avals(s, n, m=64):
     """The VMEM/SMEM scratch the streamed ADC kernel declares at the
-    sift1m cell's widths over an n-row shard."""
+    sift1m cell's widths (PQ M=64 unless given) over an n-row shard."""
     from repro.kernels.beam_fused import kernel as bk
-    b, l, m = 64, 256, 64
+    b, l = 64, 256
     jaxpr = jax.make_jaxpr(functools.partial(
         bk.beam_hops_adc_stream, max_hops=l))(
             s((n, R)), s((n, m)), s((b, m, K)), s((b, l)), s((b, l)),
@@ -155,5 +156,55 @@ def test_adc_stream_compiles_at_cell_widths_and_scratch_ignores_n(spec):
         s((b, l)), l).compile()
     assert "tpu_custom_call" in compiled.as_text()
     small = _stream_scratch_avals(s, n)
-    assert len(small) == 8
+    # two row-gather sets of four, and the sub-space-major codes
+    assert len(small) == 9
     assert small == _stream_scratch_avals(s, 2 ** 20)
+
+
+# the gist1m cell's widths: n=65,536, B=64, l = max_hops = 256, R=32,
+# K=256, 1,024 entry candidates, d=960
+CELL_N, CELL_B, CELL_L, CELL_E, GIST_D = 65_536, 64, 256, 1_024, 960
+
+
+@pytest.mark.parametrize("m", (64, 128, 240))
+def test_adc_kernels_compile_at_pq_width(spec, m):
+    """The streamed ADC hop loop and the entry-scoring kernel compile in
+    the default scoped VMEM at PQ M=64 (sift1m), 128 and 240 (gist1m):
+    their scoring holds one group of sub-spaces at a time, whatever M."""
+    from repro.kernels.beam_fused import kernel as bk
+    from repro.kernels.pq_adc import kernel as pk
+    s = spec
+    n, b, l = CELL_N, CELL_B, CELL_L
+    hop = bk.beam_hops_adc_stream.lower(
+        s((n, R)), s((n, m)), s((b, m, K)), s((b, l)), s((b, l)),
+        s((b, l)), l).compile()
+    assert "tpu_custom_call" in hop.as_text()
+    entry = jax.jit(pk.pq_adc_pallas).lower(
+        s((b, m, K)), s((CELL_E, m), jnp.int32)).compile()
+    assert "tpu_custom_call" in entry.as_text()
+
+
+def test_adc_stream_scratch_does_not_grow_with_m(spec):
+    """The streamed ADC hop loop declares the same scratch at M=240 as at
+    M=64: code rows arrive one 128-lane part at a time, and the
+    sub-space-major codes scratch is 128 rows at any M."""
+    assert (_stream_scratch_avals(spec, CELL_N, m=240)
+            == _stream_scratch_avals(spec, CELL_N, m=64))
+
+
+def test_batched_search_compiles_at_gist_widths(spec):
+    """The whole serve step of the gist1m cell: D=960, PQ M=240 (4-dim
+    sub-spaces), n=65,536, on the backend `auto` picks there."""
+    from repro.serve.ann_engine import batched_search, resolve_backend
+    s = spec
+    n, m, l = CELL_N, 240, CELL_L
+    backend = resolve_backend("auto", n=n, r=R, m=m, k=K, l=l, max_hops=l,
+                              platform="tpu")
+    assert backend == "fused_stream"
+    compiled = batched_search.lower(
+        s((n, GIST_D)), s((n, R), jnp.int32), s((n, m), jnp.uint8),
+        s((m, K, GIST_D // m)), s((CELL_E,), jnp.int32),
+        s((CELL_E, m), jnp.uint8), s((CELL_B, GIST_D)), s((n,), jnp.bool_),
+        k=10, l=l, max_hops=l, n_entry=4, rerank=l,
+        backend=backend).compile()
+    assert "tpu_custom_call" in compiled.as_text()
