@@ -37,6 +37,12 @@ Every hop also records its frontier pick into a (TB, max_hops) trace
 (the build frontier's visited set), and the program ends by emitting the
 *next* frontier pick and a done mask so callers can chain hop programs.
 
+The ADC kernels take the count of live rows as a scalar-prefetch operand
+(`n_live`): a grid step whose TB rows are all padding runs no hop loop,
+writes the empty result of its rows, and asks for no new input block
+(`_guarded`, `_tile_specs`).  The serve path pads each batch to one
+compiled shape, so a batch of few real rows costs the tiles it fills.
+
 Two execution modes share the hop loop and differ only in where the
 corpus lives:
 
@@ -516,45 +522,98 @@ def _out_shapes(b, l, max_hops):
 
 
 def _out_specs(tile_b, l, max_hops):
-    return (pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, max_hops), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, max_hops), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, 1), lambda i: (i, 0)))
+    # `*_`: a guarded kernel's index maps also get the live-row count
+    return tuple(pl.BlockSpec((tile_b, w), lambda i, *_: (i, 0))
+                 for w in (l, l, l, 1, max_hops, max_hops, 1, 1))
+
+
+def _live_tile(i, n_live_ref, tile_b: int):
+    """Grid step `i` clamped to the last tile that holds a live row: a
+    skipped step asks for the block the pipeline already holds, so it
+    issues no input DMA."""
+    last = jax.lax.div(jnp.maximum(n_live_ref[0] - 1, 0), tile_b)
+    return jnp.minimum(i, last)
+
+
+def _write_empty(outs) -> None:
+    """The eight outputs of rows whose pool has no frontier: empty pool
+    (-1, +inf, unexpanded), no hop, an empty trace, no next pick, done."""
+    for ref, fill in zip(outs, (-1, jnp.inf, 0, 0, -1, jnp.inf, -1, 1)):
+        ref[...] = jnp.full(ref.shape, fill, ref.dtype)
+
+
+def _guarded(kernel, tile_b: int):
+    """`kernel(*refs)` behind the live-row guard: the scalar-prefetch
+    count of live rows comes first, a grid step whose rows are all
+    padding (rows >= n_live) runs no hop loop and writes the empty result
+    of each row instead (`_write_empty`; the outputs follow the six
+    inputs)."""
+    def run(n_live_ref, *refs):
+        live = pl.program_id(0) * tile_b < n_live_ref[0]
+        pl.when(live)(lambda: kernel(*refs))
+        pl.when(jnp.logical_not(live))(lambda: _write_empty(refs[6:14]))
+    return run
+
+
+def _live_rows(n_live, b: int):
+    """The live-row operand: `n_live` (None = all b rows) clipped to
+    [0, b], as int32 (1,)."""
+    n = b if n_live is None else n_live
+    return jnp.clip(jnp.asarray(n, jnp.int32), 0, b).reshape(1)
+
+
+def _tile_specs(tile_b: int, l: int, tables_shape):
+    """Block specs of the per-tile inputs (tables, pool ids, dists,
+    expanded), clamped to the last live tile (`_live_tile`)."""
+    def at(nd):
+        return lambda i, n_live_ref: (_live_tile(i, n_live_ref, tile_b),
+                                      ) + (0,) * (nd - 1)
+    return [pl.BlockSpec((tile_b,) + tuple(tables_shape[1:]), at(3)),
+            pl.BlockSpec((tile_b, l), at(2)),
+            pl.BlockSpec((tile_b, l), at(2)),
+            pl.BlockSpec((tile_b, l), at(2))]
+
+
+def _guarded_call(kernel, corpus_specs, scratch_shapes, *, b: int, l: int,
+                  max_hops: int, tile_b: int, tables_shape, interpret: bool):
+    """The `pallas_call` of an ADC hop-loop kernel behind the live-row
+    guard (`_guarded`): one scalar-prefetch operand (`_live_rows`) ahead
+    of the two corpus operands, the tables and the pool triplet."""
+    return pl.pallas_call(
+        _guarded(kernel, tile_b),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b // tile_b,),
+            in_specs=corpus_specs + _tile_specs(tile_b, l, tables_shape),
+            out_specs=_out_specs(tile_b, l, max_hops),
+            scratch_shapes=scratch_shapes),
+        out_shape=_out_shapes(b, l, max_hops),
+        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "tile_b", "n_chunk",
                                              "interpret"))
 def beam_hops_adc_pallas(adj, codes, tables, pool_ids, pool_d, pool_exp,
                          max_hops: int, tile_b: int = 8, n_chunk: int = 2048,
-                         interpret: bool = False):
+                         interpret: bool = False, n_live=None):
     """adj (N, R) f32, codes (N, M) f32, tables (B, M, K) f32, seeded pool
     (B, L) f32 triplet.  B % tile_b == 0 and N % n_chunk == 0 (ops pads).
-    Returns the 8-tuple of `_out_shapes` (hops/next/done as (B, 1))."""
+    `n_live` (traced int32; None = all B) is the count of live rows: a
+    grid step of rows >= n_live only writes their empty result
+    (`_guarded`).  Returns the 8-tuple of `_out_shapes` (hops/next/done
+    as (B, 1))."""
     b, l = pool_ids.shape
     n = adj.shape[0]
     _check_tiling(b, tile_b, n, n_chunk)
-    full = lambda shape: pl.BlockSpec(shape, lambda i: tuple(0 for _ in shape))
-    return pl.pallas_call(
+    full = lambda shape: pl.BlockSpec(shape,
+                                      lambda *_: tuple(0 for _ in shape))
+    return _guarded_call(
         functools.partial(_beam_adc_kernel, max_hops=max_hops, n=n,
                           n_chunk=n_chunk),
-        grid=(b // tile_b,),
-        in_specs=[
-            full(adj.shape),
-            full(codes.shape),
-            pl.BlockSpec((tile_b,) + tables.shape[1:], lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-        ],
-        out_specs=_out_specs(tile_b, l, max_hops),
-        out_shape=_out_shapes(b, l, max_hops),
-        scratch_shapes=[subspace_scratch(tile_b * adj.shape[1])],
-        interpret=interpret,
-    )(adj, codes, tables, pool_ids, pool_d, pool_exp)
+        [full(adj.shape), full(codes.shape)],
+        [subspace_scratch(tile_b * adj.shape[1])],
+        b=b, l=l, max_hops=max_hops, tile_b=tile_b,
+        tables_shape=tables.shape, interpret=interpret,
+    )(_live_rows(n_live, b), adj, codes, tables, pool_ids, pool_d, pool_exp)
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "tile_b", "n_chunk",
@@ -619,39 +678,31 @@ def _stream_scratch(tile_b: int, r: int, row_w: int):
                                              "interpret"))
 def beam_hops_adc_stream(adj, codes, tables, pool_ids, pool_d, pool_exp,
                          max_hops: int, tile_b: int = 8, n_chunk: int = 2048,
-                         interpret: bool = False):
+                         interpret: bool = False, n_live=None):
     """`beam_hops_adc_pallas` with adj/codes streamed from HBM: the corpus
     operands get `memory_space=ANY` block specs (never staged into VMEM by
     the pipeline) and each hop DMA-copies the TB frontier adjacency rows,
     then the TB*R neighbor code rows, into VMEM row buffers.  Bit-
-    identical outputs to the resident kernel at every config; VMEM
-    footprint is `stream_vmem_bytes` -- independent of N, so shards far
-    larger than VMEM serve from one grid step.  `n_chunk` is only the
-    row multiple N must have (`ops.beam_hops` pads to it); the gather
-    does not depend on it."""
+    identical outputs to the resident kernel at every config, the
+    `n_live` guard included; a skipped grid step also moves no tables
+    block (`_tile_specs`).  VMEM footprint is `stream_vmem_bytes` --
+    independent of N, so shards far larger than VMEM serve from one grid
+    step.  `n_chunk` is only the row multiple N must have
+    (`ops.beam_hops` pads to it); the gather does not depend on it."""
     b, l = pool_ids.shape
     n = adj.shape[0]
     _check_tiling(b, tile_b, n, n_chunk)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    return pl.pallas_call(
+    return _guarded_call(
         functools.partial(_beam_adc_stream_kernel, max_hops=max_hops, n=n,
                           r=adj.shape[1]),
-        grid=(b // tile_b,),
-        in_specs=[
-            any_spec,
-            any_spec,
-            pl.BlockSpec((tile_b,) + tables.shape[1:], lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, l), lambda i: (i, 0)),
-        ],
-        out_specs=_out_specs(tile_b, l, max_hops),
-        out_shape=_out_shapes(b, l, max_hops),
-        scratch_shapes=(_stream_scratch(tile_b, adj.shape[1],
-                                        min(codes.shape[1], _LANES))
-                        + [subspace_scratch(tile_b * adj.shape[1])]),
-        interpret=interpret,
-    )(_row_view(adj), _row_view(codes), tables, pool_ids, pool_d, pool_exp)
+        [any_spec, any_spec],
+        (_stream_scratch(tile_b, adj.shape[1], min(codes.shape[1], _LANES))
+         + [subspace_scratch(tile_b * adj.shape[1])]),
+        b=b, l=l, max_hops=max_hops, tile_b=tile_b,
+        tables_shape=tables.shape, interpret=interpret,
+    )(_live_rows(n_live, b), _row_view(adj), _row_view(codes), tables,
+      pool_ids, pool_d, pool_exp)
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "tile_b", "n_chunk",

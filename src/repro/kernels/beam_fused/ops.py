@@ -22,7 +22,13 @@ backend:
   `beam_hops_ref`;
 - "ref": pure jnp scan, bit-identical to the unfused serve hop loop;
 - "auto": on TPU, "pallas" when the resident footprint fits
-  `vmem_budget_bytes()` else "stream"; "ref" elsewhere.
+  `vmem_budget_bytes()` else "stream"; "ref" elsewhere (`resolve`).
+
+`n_live` (a traced int32, default all B rows) says that only rows
+< n_live are live: the ADC kernels run no hop loop for a `tile_b` tile
+of rows >= n_live and return the empty result for its rows (pool
+(-1, +inf, unexpanded), 0 hops, done).  Rows < n_live are unchanged.
+"ref" and the exact-L2 kernels ignore it and compute every row.
 """
 from __future__ import annotations
 
@@ -37,6 +43,24 @@ from .ref import beam_hops_ref
 
 BACKENDS = ("auto", "pallas", "interpret", "ref", "stream",
             "stream_interpret")
+TILE_B = 8       # query rows per grid step
+
+
+def resolve(backend: str, n: int, r: int, *, l: int, max_hops: int,
+            tile_b: int = TILE_B, n_chunk: int = 2048,
+            platform: str | None = None, **dims) -> str:
+    """The concrete backend `beam_hops` runs for `backend` over an (n, r)
+    adjacency, with `dims` m=, k= (ADC) or d= (exact L2): "auto" is
+    "pallas" on a TPU when the resident footprint fits, else "stream";
+    "ref" off a TPU.  Other values pass through."""
+    if backend != "auto":
+        return backend
+    platform = jax.default_backend() if platform is None else platform
+    if platform != "tpu":
+        return "ref"
+    fits = fits_vmem(n, r, l=l, max_hops=max_hops, tile_b=tile_b,
+                     n_chunk=min(n_chunk, max(n, 128)), **dims)
+    return "pallas" if fits else "stream"
 
 
 def _pad_rows(a, mult: int, fill=0):
@@ -51,7 +75,8 @@ def _pad_rows(a, mult: int, fill=0):
                                              "n_chunk"))
 def beam_hops(adj, pool_ids, pool_d, pool_exp, max_hops: int,
               tables=None, codes=None, x=None, n2=None, queries=None,
-              backend: str = "auto", tile_b: int = 8, n_chunk: int = 2048):
+              backend: str = "auto", tile_b: int = TILE_B,
+              n_chunk: int = 2048, n_live=None):
     """Fused beam-hop loop.  adj (N, R) int32 with -1 pad; the seeded pool
     (B, L) triplet must satisfy the `pool_merge` invariant (sorted by
     (dist, id), invalid = (-1, +inf, False)).
@@ -65,16 +90,10 @@ def beam_hops(adj, pool_ids, pool_d, pool_exp, max_hops: int,
                          f"got {backend!r}")
     mode = "adc" if codes is not None else "l2"
     nc = min(n_chunk, max(adj.shape[0], 128))
-    if backend == "auto":
-        if jax.default_backend() == "tpu":
-            dims = (dict(m=codes.shape[1], k=tables.shape[2])
-                    if mode == "adc" else dict(d=x.shape[1]))
-            fits = fits_vmem(adj.shape[0], adj.shape[1],
-                             l=pool_ids.shape[1], max_hops=max_hops,
-                             tile_b=tile_b, n_chunk=nc, **dims)
-            backend = "pallas" if fits else "stream"
-        else:
-            backend = "ref"
+    dims = (dict(m=codes.shape[1], k=tables.shape[2]) if mode == "adc"
+            else dict(d=x.shape[1]))
+    backend = resolve(backend, *adj.shape, l=pool_ids.shape[1],
+                      max_hops=max_hops, tile_b=tile_b, n_chunk=nc, **dims)
     if backend == "ref":
         return beam_hops_ref(adj, pool_ids, pool_d, pool_exp, max_hops,
                              mode=mode, tables=tables, codes=codes,
@@ -92,7 +111,7 @@ def beam_hops(adj, pool_ids, pool_d, pool_exp, max_hops: int,
         out = fn(adj_p, _pad_rows(codes.astype(jnp.float32), nc),
                  _pad_rows(tables.astype(jnp.float32), tile_b),
                  pids, pd, pexp, max_hops, tile_b=tile_b, n_chunk=nc,
-                 interpret=interpret)
+                 interpret=interpret, n_live=n_live)
     else:
         xn = jnp.concatenate(
             [x.astype(jnp.float32), n2.astype(jnp.float32)[:, None]], axis=1)

@@ -57,7 +57,8 @@ import numpy as np
 from repro.build.pool import pool_merge as _pool_merge
 from repro.core.pq import adc_tables as _adc_tables
 from repro.kernels import beam_fused
-from repro.kernels.beam_fused.ops import beam_hops
+from repro.kernels.beam_fused.ops import TILE_B, beam_hops
+from repro.kernels.beam_fused.ops import resolve as resolve_hop_backend
 from repro.kernels.l2_topk.ops import l2_topk_rowwise
 from repro.kernels.pq_adc.ops import pq_adc, pq_adc_rowwise
 from repro.utils.faults import InjectedFault
@@ -130,7 +131,7 @@ class EngineConfig:
                                              "rerank", "backend"))
 def batched_search(x, adj, codes, codebooks, entry_cands, entry_codes,
                    queries, tomb, k: int, l: int, max_hops: int, n_entry: int,
-                   rerank: int, backend: str):
+                   rerank: int, backend: str, n_live=None):
     """One fixed-shape search step for a whole query batch.
 
     x (N, D) f32; adj (N, R) int32 VID neighbors, -1 pad; codes (N, M);
@@ -139,8 +140,10 @@ def batched_search(x, adj, codes, codebooks, entry_cands, entry_codes,
     freshness -- tombstoned VIDs stay navigable in the beam but are masked
     at the exact re-rank, so they can never reach the returned top-k; the
     mask is a traced argument, so flipping tombstones never recompiles).
-    Returns (ids (B, k) int32 with -1 pad, dists (B, k) f32 ascending,
-    hops_used (B,) int32).
+    `n_live` (traced int32; None = all B) counts the real rows at the top
+    of a padded batch: the fused ADC hop loop skips the tiles of rows
+    >= n_live, whose answers are then empty.  Returns (ids (B, k) int32
+    with -1 pad, dists (B, k) f32 ascending, hops_used (B,) int32).
     """
     b = queries.shape[0]
     queries = queries.astype(jnp.float32)
@@ -173,7 +176,7 @@ def batched_search(x, adj, codes, codebooks, entry_cands, entry_codes,
             # --- one VMEM-resident program for the whole hop loop
             pool_ids, pool_d, pool_exp, hops, *_ = beam_hops(
                 adj, pool_ids, pool_d, pool_exp, max_hops,
-                tables=tables, codes=codes_i, backend=inner)
+                tables=tables, codes=codes_i, backend=inner, n_live=n_live)
         else:
             def step(state, _):
                 pool_ids, pool_d, pool_exp, hops = state
@@ -239,6 +242,10 @@ class BatchedANNEngine:
         # int32 on the host, and the hops its hop loop ran
         self.last_hops: Optional[np.ndarray] = None
         self.last_hops_run: Optional[int] = None
+        # the hop-loop tiles that call ran, and its grid's tiles; None
+        # where its hop loop skips no tile (`_hop_tiles`)
+        self.last_tiles_run: Optional[int] = None
+        self.last_tiles: Optional[int] = None
 
     @classmethod
     def from_index(cls, idx, config: Optional[EngineConfig] = None):
@@ -304,9 +311,22 @@ class BatchedANNEngine:
             mask[ids] = True
         self.tomb = jnp.asarray(mask)
 
+    def _hop_tiles(self, l: int, max_hops: int, b: int, n_live: int):
+        """(tiles run, tiles) of a call's hop loop: ceil(n_live / TILE_B)
+        of ceil(b / TILE_B) where it runs a guarded Pallas kernel, else
+        (None, None)."""
+        dims = dict(n=self.n, r=self.adj.shape[1], m=self.codes.shape[1],
+                    k=self.codebooks.shape[1], l=l, max_hops=max_hops)
+        backend = resolve_backend(self.config.backend, **dims)
+        if backend not in _FUSED_INNER:
+            return None, None
+        if resolve_hop_backend(_FUSED_INNER[backend], **dims) == "ref":
+            return None, None
+        return -(-n_live // TILE_B), -(-b // TILE_B)
+
     def search_batch(self, queries: np.ndarray, k: int, *,
                      l: Optional[int] = None, max_hops: Optional[int] = None,
-                     exclude=None):
+                     exclude=None, rows: Optional[int] = None):
         """queries (B, D) -> (ids (B, k) int64 with -1 pad, dists (B, k)).
 
         `l` / `max_hops` optionally shrink the pool / hop budget for this
@@ -320,8 +340,14 @@ class BatchedANNEngine:
         never appear in the returned top-k.  Accepts an iterable of VIDs
         or a (N,) bool mask.
 
-        The call's hops per row are left in `last_hops`, and the hops its
-        hop loop ran in `last_hops_run`.
+        `rows` says that only the first `rows` queries are real and the
+        rest pad the batch to its compiled shape (None = all): the fused
+        hop loop skips the tiles of padding rows, whose answers are then
+        empty.  It is a traced argument, so every fill runs one program.
+
+        The call's hops per row are left in `last_hops`, the hops its hop
+        loop ran in `last_hops_run`, and the tiles it ran and had in
+        `last_tiles_run` and `last_tiles` (`_hop_tiles`).
         """
         if self._fault is not None:
             raise self._fault
@@ -350,16 +376,21 @@ class BatchedANNEngine:
                     mask[ids] = True
                 extra = mask
             tomb = tomb | jnp.asarray(extra)
+        b = q.shape[0]
+        n_live = b if rows is None else min(max(int(rows), 0), b)
         out = batched_search(
             self.x, self.adj, self.codes, self.codebooks, self.entry_cands,
             self.entry_codes, q, tomb, k=k, l=l_eff,
             max_hops=hops, n_entry=self._n_entry,
-            rerank=rerank, backend=self.config.backend)
+            rerank=rerank, backend=self.config.backend,
+            n_live=np.int32(n_live))
         with telemetry.span(telemetry.DEVICE_WAIT):
             jax.block_until_ready(out)
         with telemetry.span(telemetry.FETCH):
             ids, dists, self.last_hops = jax.device_get(out)
         self.last_hops_run = hops
+        self.last_tiles_run, self.last_tiles = self._hop_tiles(
+            l_eff, hops, b, n_live)
         return ids.astype(np.int64), dists
 
     def memory_bytes(self) -> int:

@@ -102,6 +102,10 @@ class ServeStatus:
     # the mean over the live shards; None where no engine reports them
     hops: Optional[np.ndarray] = None    # (B,)
     hops_run: Optional[float] = None
+    # hop-loop tiles run and tiles of its grid, the mean over the live
+    # shards; None where no engine's hop loop skips tiles
+    tiles_run: Optional[float] = None
+    tiles: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -112,6 +116,7 @@ class _ExecState:
     l: Optional[int]
     max_hops: Optional[int]
     exclude: Optional[Sequence] = None   # per-shard local tombstone masks
+    rows: Optional[int] = None           # real rows at the batch's top
     b: int = 0
     mask: Optional[np.ndarray] = None
     results: dict = dataclasses.field(default_factory=dict)
@@ -120,6 +125,8 @@ class _ExecState:
     down: list = dataclasses.field(default_factory=list)
     hops: list = dataclasses.field(default_factory=list)
     hops_run: list = dataclasses.field(default_factory=list)
+    tiles_run: list = dataclasses.field(default_factory=list)
+    tiles: list = dataclasses.field(default_factory=list)
     ids: Optional[np.ndarray] = None
     dists: Optional[np.ndarray] = None
 
@@ -139,16 +146,18 @@ class InstructionInterpreter:
     def execute(self, program: Sequence[Instruction], queries: np.ndarray,
                 k: int, *, l: Optional[int] = None,
                 max_hops: Optional[int] = None,
-                exclude: Optional[Sequence] = None):
+                exclude: Optional[Sequence] = None,
+                rows: Optional[int] = None):
         """Run one query batch through the program.
 
         `exclude` is an optional per-shard sequence of shard-local VID
         lists/masks (the delta-layer tombstone mask, already scattered to
         local id space by the runtime); each live RUN forwards its shard's
-        entry to the engine.  Returns (ids (B, k) int64, dists (B, k),
-        ServeStatus)."""
+        entry to the engine, and `rows` (the real rows at the top of a
+        padded batch, None = all) to every shard.  Returns (ids (B, k)
+        int64, dists (B, k), ServeStatus)."""
         st = _ExecState(queries=queries, k=k, l=l, max_hops=max_hops,
-                        exclude=exclude)
+                        exclude=exclude, rows=rows)
         for ins in program:
             self._dispatch[ins.op](st, ins)
         status = ServeStatus(
@@ -158,6 +167,9 @@ class InstructionInterpreter:
         if st.hops:
             status.hops = np.mean(st.hops, axis=0)
             status.hops_run = float(np.mean(st.hops_run))
+        if st.tiles:
+            status.tiles_run = float(np.mean(st.tiles_run))
+            status.tiles = float(np.mean(st.tiles))
         return st.ids, st.dists, status
 
     # --- opcodes ------------------------------------------------------------
@@ -184,7 +196,7 @@ class InstructionInterpreter:
             try:
                 ids_s, d_s = rep.worker.run(rep, st.queries, ks,
                                             l=st.l, max_hops=st.max_hops,
-                                            exclude=excl)
+                                            exclude=excl, rows=st.rows)
             except _REPLICA_FAILURES as e:       # replica down, try next
                 self.placement.record_failure(rep, e)
                 continue
@@ -193,6 +205,10 @@ class InstructionInterpreter:
             if hops is not None:
                 st.hops.append(hops)
                 st.hops_run.append(rep.engine.last_hops_run)
+            tiles = getattr(rep.engine, "last_tiles", None)
+            if tiles is not None:
+                st.tiles.append(tiles)
+                st.tiles_run.append(rep.engine.last_tiles_run)
             return
 
     def _gather(self, st: _ExecState, ins: Instruction) -> None:

@@ -58,11 +58,11 @@ class MeshWorker:
 
     def run(self, replica: "Replica", queries: np.ndarray, k: int, *,
             l: Optional[int] = None, max_hops: Optional[int] = None,
-            exclude=None):
+            exclude=None, rows: Optional[int] = None):
         """Execute one shard-batch on this worker's engine copy."""
         return replica.engine.search_batch(queries, k, l=l,
                                            max_hops=max_hops,
-                                           exclude=exclude)
+                                           exclude=exclude, rows=rows)
 
     def __repr__(self) -> str:
         bound = [(r.shard, r.replica) for r in self.replicas]

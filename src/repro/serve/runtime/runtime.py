@@ -142,7 +142,7 @@ class ServeRuntime:
                     with_status: bool = False, *,
                     l: Optional[int] = None,
                     max_hops: Optional[int] = None,
-                    exclude=None):
+                    exclude=None, rows: Optional[int] = None):
         """(B, D) queries -> global (ids (B, k) int64, dists (B, k)).
 
         One walk of the compiled program: SCATTER stages the batch and
@@ -153,9 +153,14 @@ class ServeRuntime:
         marked down and its RUN retried on the next replica.  With every
         shard down the answer is all -1/+inf.  `with_status=True`
         additionally returns a `ServeStatus` whose `degraded` flags mark
-        answers that missed at least one shard, and whose `hops` and
-        `hops_run` count each row's hop loop.  `l`/`max_hops` shrink the
-        beam for this batch only (deadline-pressed micro-batches).
+        answers that missed at least one shard, whose `hops` and
+        `hops_run` count each row's hop loop, and whose `tiles_run` and
+        `tiles` count its tiles.  `l`/`max_hops` shrink the beam for this
+        batch only (deadline-pressed micro-batches).
+        `rows` says that only the first `rows` queries are real and the
+        rest pad the batch to its compiled shape (None = all): each
+        shard's hop loop skips the tiles of padding rows, whose answers
+        are then empty.
         `exclude` is an iterable of *global* tombstoned ids (streaming
         freshness); they are scattered to shard-local masks and never
         appear in the merged top-k.
@@ -163,7 +168,7 @@ class ServeRuntime:
         with telemetry.span(telemetry.STEP):
             ids, dists, status = self.interpreter.execute(
                 self.program, queries, k, l=l, max_hops=max_hops,
-                exclude=self._scatter_exclude(exclude))
+                exclude=self._scatter_exclude(exclude), rows=rows)
         if not with_status:
             return ids, dists
         return ids, dists, status

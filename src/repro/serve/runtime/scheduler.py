@@ -11,7 +11,9 @@ earliest-deadline-first heap, and drain into fixed-shape micro-batches:
   asserted in tests/test_runtime.py).
 - **Padding** tiles every micro-batch up to exactly `max_batch` rows, so
   each beam tier compiles one (B, D) signature for the lifetime of the
-  server (the fixed-shape contract of `BatchedANNEngine`).
+  server (the fixed-shape contract of `BatchedANNEngine`).  The count of
+  real rows goes with the batch (`rows=`), so the hop loop skips the
+  tiles that hold only padding.
 - **Adaptive beam width** re-triages each popped request by its remaining
   slack: a request whose slack has fallen under `shrink_slack * slo`
   executes on the shrunk `BeamTier` (smaller pool `l` / `max_hops` =
@@ -78,6 +80,8 @@ class Completion:
     queued: Optional[float] = None   # due time -> its batch's dispatch, s
     hops: Optional[float] = None     # hops that expanded a node for it
     hops_run: Optional[float] = None  # hops its batch's hop loop ran
+    tiles_run: Optional[float] = None  # hop-loop tiles its batch ran
+    tiles: Optional[float] = None     # hop-loop tiles of its batch's grid
 
 
 class RequestQueue:
@@ -178,7 +182,7 @@ class Scheduler:
             q = np.concatenate([q, np.tile(q[:1], (cfg.max_batch - b, 1))])
         t0 = time.perf_counter()
         ids, dists, status = self.runtime.serve_batch(
-            q, cfg.k, with_status=True, **self._tier_args(tier_idx))
+            q, cfg.k, with_status=True, rows=b, **self._tier_args(tier_idx))
         dt = time.perf_counter() - t0
         return ids[:b], dists[:b], status, dt
 
@@ -198,8 +202,8 @@ class Scheduler:
         Completion's `queued` is the part before its batch was dispatched,
         and `latency - queued` the batch's service.  Each formation round
         is one `bamg.round` span whose `round` the Completions carry; the
-        runtime's `hops`/`hops_run` (where its status has them) are copied
-        per row."""
+        runtime's `hops`/`hops_run` and `tiles_run`/`tiles` (where its
+        status has them) are copied per row."""
         reqs = sorted(requests, key=lambda r: (r.arrival, r.rid))
         if not reqs:
             return []
@@ -220,6 +224,8 @@ class Scheduler:
                     # duck-typed runtimes may report no hops
                     hops = getattr(status, "hops", None)
                     hops_run = getattr(status, "hops_run", None)
+                    tiles_run = getattr(status, "tiles_run", None)
+                    tiles = getattr(status, "tiles", None)
                     for j, r in enumerate(batch):
                         out.append(Completion(
                             rid=r.rid, ids=ids[j], dists=dists[j],
@@ -229,7 +235,8 @@ class Scheduler:
                             degraded=bool(status.degraded[j]) or tier_idx > 0,
                             round=rnd, queued=sent - r.arrival,
                             hops=None if hops is None else float(hops[j]),
-                            hops_run=hops_run))
+                            hops_run=hops_run, tiles_run=tiles_run,
+                            tiles=tiles))
             rnd += 1
         out.sort(key=lambda c: c.rid)
         return out

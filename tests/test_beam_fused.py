@@ -212,6 +212,25 @@ def test_beam_hops_stream_bitwise_matches_resident(n_chunk):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("backend", ("interpret", "stream_interpret"))
+@pytest.mark.parametrize("n_live", (1, 7, 8, 9, 57, 64))
+def test_beam_hops_skips_tiles_of_padding_rows(backend, n_live):
+    """With `n_live` given, a tile of rows >= n_live runs no hop loop:
+    the rows of every tile that ran are bit-identical to the call without
+    a count, and every row of a skipped tile holds the empty result."""
+    adj, _, codes, tables, _, pi, pd, pe = _graph(b=64)
+    kw = dict(tables=tables, codes=codes, backend=backend, tile_b=8,
+              n_chunk=128)
+    full = beam_hops(adj, pi, pd, pe, 6, **kw)
+    out = beam_hops(adj, pi, pd, pe, 6, n_live=jnp.int32(n_live), **kw)
+    ran = -(-n_live // 8) * 8
+    empty = (-1, np.inf, False, 0, -1, np.inf, -1, True)
+    for got, want, fill in zip(out, full, empty):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_array_equal(got[:ran], want[:ran])
+        assert (got[ran:] == fill).all()
+
+
 def _edge_graph(mode, d=6, n=300, r=8, m=4, k=16, b=8, l=12, seed=11):
     """A corpus of the row gather's edge cases: the last row (id n-1) in
     seeds and adjacency, rows whose adjacency is all -1, pool rows with

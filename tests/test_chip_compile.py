@@ -208,3 +208,26 @@ def test_batched_search_compiles_at_gist_widths(spec):
         k=10, l=l, max_hops=l, n_entry=4, rerank=l,
         backend=backend).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("m, d", ((64, 128), (240, GIST_D)))
+def test_adc_stream_with_live_rows_compiles(spec, m, d):
+    """The streamed ADC hop loop with a traced live-row count (the
+    scalar-prefetch operand, the guarded tile body and the input blocks
+    clamped to the last live tile), alone and inside the whole serve
+    step, at the sift1m (M=64) and gist1m (M=240, d=960) cells' widths."""
+    from repro.kernels.beam_fused import kernel as bk
+    from repro.serve.ann_engine import batched_search
+    s = spec
+    n, b, l = CELL_N, CELL_B, CELL_L
+    n_live = s((), jnp.int32)
+    hop = bk.beam_hops_adc_stream.lower(
+        s((n, R)), s((n, m)), s((b, m, K)), s((b, l)), s((b, l)),
+        s((b, l)), l, n_live=n_live).compile()
+    assert "tpu_custom_call" in hop.as_text()
+    step = batched_search.lower(
+        s((n, d)), s((n, R), jnp.int32), s((n, m), jnp.uint8),
+        s((m, K, d // m)), s((CELL_E,), jnp.int32), s((CELL_E, m), jnp.uint8),
+        s((b, d)), s((n,), jnp.bool_), k=10, l=l, max_hops=l, n_entry=4,
+        rerank=l, backend="fused_stream", n_live=n_live).compile()
+    assert "tpu_custom_call" in step.as_text()
